@@ -5,6 +5,7 @@ import time
 import jax
 
 from repro import obs
+from repro.launch.compile_cache import use_compile_cache
 
 
 def time_call(fn, *args, warmup=2, iters=5):
@@ -93,6 +94,7 @@ def run_main(run, argv=None, header: bool = False):
                         help="shrink the workload (CI smoke)")
     add_trace_arg(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
     if header:
         print("name,us_per_call,derived")
     with tracing(args.trace_out):

@@ -1,56 +1,25 @@
-"""Version-compat shims for the jax API surface this repo targets.
+"""Thin wrappers over the jax API calls this repo makes in several places.
 
-The codebase is written against the current jax API (``jax.shard_map``,
-``jax.sharding.AxisType``); older jax releases (< 0.4.38) ship the same
-functionality under experimental/implicit spellings.  Everything that needs
-one of the moved symbols imports it from here.
+Everything that shards a body or builds a mesh goes through here, so the
+spelling of those calls is decided once.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                      # jax < 0.4.38
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore # noqa
-
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-    """``shard_map`` with the replication-check flag normalized.
+    """``jax.shard_map`` with the replication check as ``check_rep``.
 
-    The flag was renamed ``check_rep`` -> ``check_vma`` (jax >= 0.6);
-    callers that shard a ``pallas_call`` body must disable it (no
-    replication rule), so route to whichever spelling this jax accepts.
+    Callers that shard a ``pallas_call`` body must disable it (no
+    replication rule); jax spells the flag ``check_vma``.
     """
-    kw = {}
-    if "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = check_rep
-    elif "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = check_rep
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version.
-
-    Older jaxlib (< 0.4.37) returns a one-element list of dicts; current
-    jax returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:                   # older jax: implicit Auto
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

@@ -73,12 +73,40 @@ def mteps(nnz: int, time_s: float) -> float:
 
 
 # --------------------------------------------------------------------------
+# Device peaks: the one table, keyed by jax's ``device_kind``
+# --------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    hbm_bytes_per_s: float
+    bf16_flops_per_s: float
+    source: str
+
+
+DEVICE_PEAKS = {
+    # jax reports a TPU v5e chip as "TPU v5 lite".
+    "TPU v5 lite": DevicePeaks(
+        hbm_bytes_per_s=819e9, bf16_flops_per_s=197e12,
+        source='Google Cloud TPU documentation, "TPU v5e"'),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Published peaks of one chip; a device not in the table is an error
+    (a roofline share against a guessed peak is no measurement)."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
+
+
+# --------------------------------------------------------------------------
 # TPU v5e model (the hardware adaptation)
 # --------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class TPUSpec:
-    hbm_bw: float = 819e9           # bytes/s per chip
-    peak_flops_bf16: float = 197e12
+    device_kind: str = "TPU v5 lite"
     ici_bw: float = 50e9            # bytes/s per link
     vpu_freq_hz: float = 940e6
     lanes: int = 128
@@ -91,6 +119,11 @@ class TPUSpec:
     # Hillclimbed kernel (see EXPERIMENTS.md §Perf): conflict-free tiles let
     # scatter retire one full tile per issue window.
     cycles_per_tile_optimized: float = 10.0
+
+    @property
+    def hbm_bw(self) -> float:
+        """HBM bytes/s per chip, from :data:`DEVICE_PEAKS`."""
+        return device_peaks(self.device_kind).hbm_bytes_per_s
 
 
 TPU_V5E = TPUSpec()

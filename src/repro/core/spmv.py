@@ -68,6 +68,7 @@ class SerpensOperator:
                 plan.seg_ids[:, ::cfg.tiles_per_chunk], sh)
             self._aux = tuple(jax.device_put(a, sh) for a in
                               (plan.aux_rows, plan.aux_cols, plan.aux_vals))
+            self._sharded_fns = {}
         else:
             self._shards = [ops.device_arrays(sm) for sm in plan.shards]
             self._auxs = [
@@ -80,6 +81,7 @@ class SerpensOperator:
                 + [a for aux in self._auxs if aux is not None for a in aux])
         if self._row_perm is not None:
             held = held + [self._row_perm]
+        self._held = tuple(held)
         self._device_bytes = int(sum(int(a.nbytes) for a in held))
 
     # -- properties -------------------------------------------------------
@@ -116,6 +118,13 @@ class SerpensOperator:
         return self._device_bytes
 
     @property
+    def stream_devices(self) -> frozenset:
+        """The devices that hold this operator's resident buffers — one per
+        mesh-axis position for a mesh-bound plan."""
+        return frozenset().union(*(a.sharding.device_set
+                                   for a in self._held))
+
+    @property
     def stream_bytes(self) -> int:
         return self.plan.stream_bytes
 
@@ -128,17 +137,14 @@ class SerpensOperator:
         return int(self.plan.idx.size)
 
     def cost_report(self, *, measure: bool = False,
-                    backend: str | None = None,
-                    bandwidth_gbps: float | None = None,
-                    iters: int = 3) -> dict:
-        """Per-shard cost-model report (stream bytes, slots, modeled
-        stream time), optionally with a measured matvec wall-time and the
-        achieved fraction of the assumed HBM roofline.  See
-        :func:`repro.obs.profile.plan_cost_report`."""
+                    backend: str | None = None, iters: int = 3) -> dict:
+        """Per-shard cost report (stream bytes, slots, padding),
+        optionally with a measured matvec wall-time and the achieved
+        fraction of the device's HBM peak; raises on a device with no
+        peak-table row.  See :func:`repro.obs.profile.plan_cost_report`."""
         from repro.obs import profile as _profile
         return _profile.plan_cost_report(
-            self, measure=measure, backend=backend,
-            bandwidth_gbps=bandwidth_gbps, iters=iters)
+            self, measure=measure, backend=backend, iters=iters)
 
     def with_mesh(self, mesh, axis: str, partition: str | None = None
                   ) -> "SerpensOperator":
@@ -301,7 +307,7 @@ class SerpensOperator:
             segment_width=cfg.segment_width,
             tiles_per_chunk=cfg.tiles_per_chunk, backend=backend)
         if self.mesh is not None:
-            return self._apply_sharded(x, run)
+            return self._apply_sharded(x, run, backend)
         pad = [(0, 0)] * x.ndim
         if plan.spec.partition == "col" and plan.num_shards > 1:
             pad[0] = (0, plan.num_shards * kp - x.shape[0])
@@ -321,7 +327,7 @@ class SerpensOperator:
         return self._finish(
             jnp.concatenate([o[:plan.block_m] for o in outs]))
 
-    def _apply_sharded(self, x, run):
+    def _apply_sharded(self, x, run, backend):
         """shard_map execution over the mesh axis (row concat / col psum)."""
         plan, axis = self.plan, self.axis
         n = plan.num_shards
@@ -337,19 +343,24 @@ class SerpensOperator:
             xp = jnp.pad(x, pad)
             x_spec = P()
 
-        def body(idx, val, seg_t, seg_c, ar, ac, av, xv):
-            xl = xv[0] if col else xv
-            acc = self._shard_acc((idx[0], val[0], seg_t[0], seg_c[0]),
-                                  (ar[0], ac[0], av[0]), xl, run)
-            if col:
-                return jax.lax.psum(acc, axis)
-            return acc[None]
+        # One jitted shard_map per backend: a fresh closure on every call
+        # would retrace and recompile the whole program.
+        f = self._sharded_fns.get(backend)
+        if f is None:
+            def body(idx, val, seg_t, seg_c, ar, ac, av, xv):
+                xl = xv[0] if col else xv
+                acc = self._shard_acc((idx[0], val[0], seg_t[0], seg_c[0]),
+                                      (ar[0], ac[0], av[0]), xl, run)
+                if col:
+                    return jax.lax.psum(acc, axis)
+                return acc[None]
 
-        f = compat.shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(axis),) * 7 + (x_spec,),
-            out_specs=P() if col else P(axis),
-            check_rep=False)  # pallas_call has no replication rule
+            f = jax.jit(compat.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(P(axis),) * 7 + (x_spec,),
+                out_specs=P() if col else P(axis),
+                check_rep=False))  # pallas_call has no replication rule
+            self._sharded_fns[backend] = f
         acc = f(self._idx, self._val, self._seg, self._seg_chunk,
                 *self._aux, xp)
         if col:

@@ -78,7 +78,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     jax.jit,
     static_argnames=("causal", "q_block", "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal=True, q_block=512, kv_block=1024,
-                    interpret=True):
+                    interpret=False):
     """q: (B, Sq, KV, G, dh); k: (B, Sk, KV, dh); v: (B, Sk, KV, dv).
 
     Returns (B, Sq, KV, G, dv).  Self-attention layout (q_offset 0);

@@ -1,14 +1,18 @@
-"""Jit'd wrappers around the Serpens kernels + the XLA stream fallback.
+"""Jit'd wrappers around the Serpens kernels + the XLA stream executor.
 
 Three execution paths, selectable via ``backend=``:
 
-  * ``"pallas"``    — the TPU kernel (``serpens_spmv.py``); on CPU it runs in
-                      ``interpret=True`` mode (used by tests).
-  * ``"xla"``       — the same Serpens stream processed as one vectorized
-                      gather/scatter in plain XLA (fast on CPU; also the
-                      paper-faithful *algorithm* without the hand kernel —
-                      used as the §Perf baseline).
-  * ``"auto"``      — pallas on TPU, xla elsewhere.
+  * ``"xla"``       — the Serpens stream processed as one vectorized
+                      gather/scatter in plain XLA.  It compiles for the
+                      TPU (v5e) at published matrix sizes and is the
+                      default on every platform.
+  * ``"pallas"``    — the hand kernel (``serpens_spmv.py``).  On a TPU it
+                      is compiled for real, never interpreted; Mosaic does
+                      not lower its gather/scatter yet, so there it raises
+                      the compiler's error.  Off the TPU it runs in the
+                      Pallas interpreter (the CPU tests).
+  * ``"auto"``      — ``"xla"``, until a Pallas kernel compiles for the
+                      chip.
 """
 from __future__ import annotations
 
@@ -95,22 +99,26 @@ def pad_x(x, num_segments, segment_width):
 def resolve_backend(backend: str | None = None) -> str:
     """Resolve a backend name to a concrete executor ("xla" | "pallas").
 
-    ``None``/``"auto"`` picks Pallas on TPU and XLA elsewhere.  Bind-time
-    callers (:class:`~repro.core.spmv.SerpensOperator`, the service)
-    resolve once and pass the concrete name down, so per-call dispatch —
-    including inside jit traces — never re-queries
-    ``jax.default_backend()``.
+    ``None``/``"auto"`` is ``"xla"`` on every platform: the Pallas kernel
+    does not lower for the TPU yet (see ``tests/test_chip_compile.py``).
+    Bind-time callers (:class:`~repro.core.spmv.SerpensOperator`, the
+    service) resolve once and pass the concrete name down.
     """
     if backend is None or backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "xla"
     if backend not in ("xla", "pallas"):
         raise ValueError(f"unknown backend {backend!r}")
     return backend
 
 
+def _interpret() -> bool:
+    """Pallas runs interpreted only off the TPU; on the chip it compiles
+    for real or raises, never falling back to the interpreter."""
+    return jax.default_backend() != "tpu"
+
+
 def run_stream(idx, val, seg_ids_tile, seg_ids_chunk, x, *, num_rows_padded,
-               segment_width, tiles_per_chunk=1, backend="auto",
-               interpret=None):
+               segment_width, tiles_per_chunk=1, backend="auto"):
     """The one backend-dispatch point for executing a Serpens stream.
 
     Accepts a 1-D x (matvec) or a 2-D ``(K_padded, N)`` x (matmat) already
@@ -130,8 +138,7 @@ def run_stream(idx, val, seg_ids_tile, seg_ids_chunk, x, *, num_rows_padded,
                                num_rows_padded=num_rows_padded,
                                segment_width=segment_width)
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = _interpret()
         if x.ndim == 1:
             return serpens_spmv.spmv_pallas(
                 idx, val, seg_ids_chunk, x.reshape(-1, segment_width),
@@ -149,7 +156,7 @@ def run_stream(idx, val, seg_ids_tile, seg_ids_chunk, x, *, num_rows_padded,
 
 def run_stream_fused(idx, val, seg_ids_tile, seg_ids_chunk, x, *, epilogue,
                      extras=(), num_rows_padded, segment_width,
-                     tiles_per_chunk=1, backend="auto", interpret=None):
+                     tiles_per_chunk=1, backend="auto"):
     """One-pass matvec **plus** a fused epilogue — the solver hot path.
 
     ``epilogue(acc2d, *extras) -> tuple of arrays`` runs with the
@@ -178,11 +185,9 @@ def run_stream_fused(idx, val, seg_ids_tile, seg_ids_chunk, x, *, epilogue,
         outs = epilogue(acc.reshape(-1, lanes), *extras)
         return acc, tuple(outs)
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return serpens_spmv.spmv_fused_pallas(
             idx, val, seg_ids_chunk, x.reshape(-1, segment_width), extras,
             epilogue=epilogue, num_rows_padded=num_rows_padded,
             segment_width=segment_width, tiles_per_chunk=tiles_per_chunk,
-            interpret=interpret)
+            interpret=_interpret())
     raise ValueError(f"unknown backend {backend!r}")

@@ -20,7 +20,11 @@ Maps the paper's accelerator (Fig. 1) onto the TPU memory hierarchy:
                             RAW window.
   CompY (α,β unit)        → fused epilogue in ops.py (y-block already local).
 
-Correctness is validated in ``interpret=True`` mode against ``ref.py``.
+Correctness is validated in ``interpret=True`` mode against ``ref.py``;
+the CPU tests ask for the interpreter explicitly.  By default the kernels
+compile for the TPU, where Mosaic refuses them today (the ``(1, W)`` x
+block, the 1-D gather ``xseg[cols]`` and the scatter-add); see
+``tests/test_chip_compile.py``.
 """
 from __future__ import annotations
 
@@ -66,7 +70,7 @@ def _spmv_kernel(seg_ids_ref, idx_ref, val_ref, x_ref, out_ref):
     static_argnames=("num_rows_padded", "segment_width", "tiles_per_chunk",
                      "interpret"))
 def spmv_pallas(idx, val, seg_ids, x2d, *, num_rows_padded, segment_width,
-                tiles_per_chunk=1, interpret=True):
+                tiles_per_chunk=1, interpret=False):
     """Raw accumulate ``A @ x`` over the Serpens stream.
 
     Args:
@@ -145,7 +149,7 @@ def _spmm_kernel(seg_ids_ref, idx_ref, val_ref, x_ref, out_ref):
     static_argnames=("num_rows_padded", "segment_width", "tiles_per_chunk",
                      "interpret"))
 def spmm_pallas(idx, val, seg_ids, x3d, *, num_rows_padded, segment_width,
-                tiles_per_chunk=1, interpret=True):
+                tiles_per_chunk=1, interpret=False):
     """A @ X for X (num_segments, W, N) → acc (num_rows_padded, N)."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -191,7 +195,7 @@ def spmm_pallas(idx, val, seg_ids, x3d, *, num_rows_padded, segment_width,
                      "tiles_per_chunk", "interpret"))
 def spmv_fused_pallas(idx, val, seg_ids, x2d, extras=(), *, epilogue,
                       num_rows_padded, segment_width, tiles_per_chunk=1,
-                      interpret=True):
+                      interpret=False):
     """``A @ x`` with a fused epilogue in the kernel's output tile loop.
 
     Identical streaming/accumulation to :func:`spmv_pallas`, but on the

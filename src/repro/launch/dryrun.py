@@ -29,9 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import ARCHS, SHAPES, get_config, valid_cells
 from repro.launch import hlo_analysis
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh, dp_axes
 from repro.launch import sharding as sh
 from repro.models import layers as L
@@ -193,7 +193,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         compile_s = time.time() - t0
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo_text = compiled.as_text()
         coll = collective_bytes(hlo_text)
         scan_aware = hlo_analysis.analyze(hlo_text)
@@ -267,6 +267,7 @@ def main():
     ap.add_argument("--kv-quant", action="store_true",
                     help="int8 KV cache (§Perf B3) for decode cells")
     args = ap.parse_args()
+    use_compile_cache()
 
     meshes = [False, True] if args.both_meshes else [args.multi_pod]
     cells = (valid_cells() if args.all

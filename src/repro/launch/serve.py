@@ -26,6 +26,8 @@ def main():
 
     import numpy as np
     import jax
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_config, reduced_config
     from repro.data.pipeline import add_modality_stubs
     from repro.launch.mesh import make_host_mesh

@@ -32,6 +32,8 @@ def main():
             f"--xla_force_host_platform_device_count={args.host_devices}")
 
     # imports after XLA_FLAGS
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_config, reduced_config
     from repro.data.pipeline import SyntheticLM, add_modality_stubs
     from repro.launch.mesh import make_host_mesh

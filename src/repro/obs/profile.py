@@ -2,7 +2,7 @@
 
 The paper's results are roofline points — achieved GB/s of A-stream
 traffic against the HBM peak — so a benchmark sweep wants, per run, the
-plan's *modeled* cost (stream bytes, slots, padding) next to its
+plan's counted cost (stream bytes, slots, padding) next to its
 *measured* wall-time.  :func:`plan_cost_report` produces exactly that for
 any :class:`~repro.core.spmv.SerpensOperator` (surfaced as
 ``op.cost_report()``), and :func:`profiler_trace` wraps a block in a
@@ -18,34 +18,27 @@ import contextlib
 import time
 import warnings
 
-# Assumed peak stream bandwidth for the modeled wall-time, GB/s.  The
-# paper's Serpens uses 16 HBM2 channels at ~12.9 GB/s effective each
-# (~206 GB/s aggregate); override per call for other parts.
-ASSUMED_BANDWIDTH_GBPS = 206.0
-
 
 def plan_cost_report(op, *, measure: bool = False,
-                     backend: str | None = None,
-                     bandwidth_gbps: float | None = None,
-                     iters: int = 3) -> dict:
-    """Cost-model report for one operator's channel-shard plan.
+                     backend: str | None = None, iters: int = 3) -> dict:
+    """Cost report for one operator's channel-shard plan.
 
-    Per shard: nnz, slots, stream bytes, padding ratio, per-lane live-slot
-    imbalance (max/mean), and the modeled stream time
-    ``bytes / bandwidth``.  With ``measure=True`` one matvec
-    is compiled + timed (median of ``iters``) and the report adds the
-    achieved GB/s and its fraction of the assumed peak — the roofline
-    position — plus per-shard measured time attributed proportionally to
-    stream bytes (shards dispatch in one call, so only the total is
-    directly observable).
+    Per shard: nnz, slots, stream bytes, padding ratio and per-lane
+    live-slot imbalance (max/mean) — all counted from the plan.  With
+    ``measure=True`` one matvec is compiled + timed (median of ``iters``)
+    and the report adds the device kind, its HBM peak from
+    :data:`repro.core.scheduler.DEVICE_PEAKS`, the achieved GB/s and its
+    fraction of that peak — the roofline position — plus per-shard
+    measured time attributed proportionally to stream bytes (shards
+    dispatch in one call, so only the total is directly observable).
+    A device with no row in the peak table (the CPU among them) raises
+    ``KeyError`` before anything is timed.
     """
     import numpy as np
     from repro.core.format import SENTINEL
-    bw = float(bandwidth_gbps or ASSUMED_BANDWIDTH_GBPS)
     plan = op.plan
     shards = []
     for i, sm in enumerate(plan.shards):
-        sb = int(sm.stream_bytes)
         # Per-lane live-slot imbalance (max/mean): the structural feature
         # the auto-tuner keys on — 1.0 is perfectly balanced lanes, higher
         # means some lanes pad while others stream.
@@ -57,10 +50,9 @@ def plan_cost_report(op, *, measure: bool = False,
             "nnz": int(sm.nnz),
             "n_aux": int(sm.n_aux),
             "slots": int(sm.idx.size),
-            "stream_bytes": sb,
+            "stream_bytes": int(sm.stream_bytes),
             "padding_ratio": float(sm.padding_ratio),
             "lane_slot_imbalance": imb,
-            "est_stream_s": sb / (bw * 1e9),
         })
     total_bytes = int(plan.stream_bytes)
     report = {
@@ -79,13 +71,13 @@ def plan_cost_report(op, *, measure: bool = False,
         "lane_assign": plan.spec.lane_assign,
         "lane_slot_imbalance": max(
             (sh["lane_slot_imbalance"] for sh in shards), default=1.0),
-        "assumed_bandwidth_gbps": bw,
-        "est_stream_s": total_bytes / (bw * 1e9),
         "shards": shards,
     }
     if measure:
-        import numpy as np
         import jax
+        from repro.core.scheduler import device_peaks
+        kind = jax.devices()[0].device_kind
+        peak_gbps = device_peaks(kind).hbm_bytes_per_s / 1e9
         x = np.random.default_rng(0).normal(
             size=op.shape[1]).astype(np.float32)
         jax.block_until_ready(op.matvec(x, backend=backend))  # compile
@@ -96,9 +88,11 @@ def plan_cost_report(op, *, measure: bool = False,
             times.append(time.perf_counter() - t0)
         times.sort()
         measured = times[len(times) // 2]
+        report["device_kind"] = kind
+        report["peak_hbm_gbps"] = peak_gbps
         report["measured_matvec_s"] = measured
         report["achieved_gbps"] = total_bytes / measured / 1e9
-        report["roofline_fraction"] = report["achieved_gbps"] / bw
+        report["roofline_fraction"] = report["achieved_gbps"] / peak_gbps
         for sh in shards:
             frac = sh["stream_bytes"] / max(total_bytes, 1)
             sh["measured_s_attributed"] = measured * frac

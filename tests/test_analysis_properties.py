@@ -29,11 +29,13 @@ CONFIGS = st.builds(
     spill_hot_rows=st.booleans(),
     lane_balance=st.sampled_from([0.0, 1.2]))
 
-SPECS = st.builds(
-    PT.PlanSpec,
-    partition=st.sampled_from(["single", "row", "col"]),
-    num_shards=st.integers(1, 3),
-    lane_assign=st.sampled_from(["modulo", "balanced"]))
+LANE_ASSIGNS = st.sampled_from(["modulo", "balanced"])
+# A "single" plan has exactly one shard (PlanSpec rejects any other count).
+SPECS = st.one_of(
+    st.builds(PT.PlanSpec, partition=st.just("single"),
+              num_shards=st.just(1), lane_assign=LANE_ASSIGNS),
+    st.builds(PT.PlanSpec, partition=st.sampled_from(["row", "col"]),
+              num_shards=st.integers(1, 3), lane_assign=LANE_ASSIGNS))
 
 COOS = st.builds(
     lambda m, k, nnz, seed: (m, k, *_coo(m, k, nnz, seed)),

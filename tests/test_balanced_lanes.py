@@ -115,7 +115,9 @@ if HAVE_HYP:
         rows, cols, vals = rand_coo(m, k, nnz, seed)
         cfg = SPILL_CFG if spill else CFG
         plan = PT.make_plan(rows, cols, vals, (m, k), cfg,
-                            PT.PlanSpec(partition, 2, "balanced"))
+                            PT.PlanSpec(partition,
+                                        1 if partition == "single" else 2,
+                                        "balanced"))
         r2, c2, v2 = plan.to_coo()
         k1, _ = coo_multiset(rows, cols, vals, (m, k))
         k2, _ = coo_multiset(r2, c2, v2, (m, k))

@@ -33,6 +33,35 @@ CFGS = [
 ]
 
 
+def test_auto_backend_is_xla():
+    """Until a Pallas kernel lowers for the chip, "auto" is the XLA stream
+    executor on every platform."""
+    assert ops.resolve_backend() == ops.resolve_backend("auto") == "xla"
+    assert ops.resolve_backend("pallas") == "pallas"
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.resolve_backend("mosaic")
+
+
+def test_pallas_kernel_compiles_unless_interpretation_is_asked_for():
+    """The kernels' default is a real compile: off the TPU that is refused
+    loudly instead of quietly falling back to the interpreter."""
+    from repro.kernels import serpens_spmv as K
+    cfg = CFGS[0]
+    rows, cols, vals, x = build(40, 120, 300, cfg, seed=21)
+    sm = F.encode(rows, cols, vals, (40, 120), cfg)
+    args = (jnp.asarray(sm.idx), jnp.asarray(sm.val),
+            jnp.asarray(sm.seg_ids),
+            jnp.asarray(np.pad(x, (0, sm.num_segments * 64 - 120))
+                        .reshape(-1, 64)))
+    kw = dict(num_rows_padded=sm.padded_rows, segment_width=64)
+    got = K.spmv_pallas(*args, **kw, interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[:40],
+                               spmv_coo_ref(rows, cols, vals, x, 40),
+                               rtol=1e-5, atol=1e-5)
+    with pytest.raises(Exception, match="(?i)interpret"):
+        K.spmv_pallas(*args, **kw)
+
+
 @pytest.mark.parametrize("cfg", CFGS)
 @pytest.mark.parametrize("m,k,nnz", [(100, 130, 700), (37, 211, 900),
                                      (256, 64, 64), (512, 4096, 3000)])
@@ -217,7 +246,7 @@ class TestFlashAttention:
         k = jnp.asarray(rng.normal(size=(b, s, kv, dh)), jnp.float32)
         v = jnp.asarray(rng.normal(size=(b, s, kv, dv)), jnp.float32)
         got = flash_attention(q, k, v, causal=causal, q_block=qb,
-                              kv_block=kb)
+                              kv_block=kb, interpret=True)
         want = self._ref(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -230,7 +259,8 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(2, 48, 2, 2, 16)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(2, 48, 2, 16)), jnp.float32)
         v = jnp.asarray(rng.normal(size=(2, 48, 2, 16)), jnp.float32)
-        a = flash_attention(q, k, v, causal=True, q_block=16, kv_block=16)
+        a = flash_attention(q, k, v, causal=True, q_block=16, kv_block=16,
+                            interpret=True)
         b = chunked_attention(q, k, v, causal=True, chunk=16, kv_block=16)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)

@@ -1,4 +1,5 @@
 """CLI launcher smoke tests (subprocess — train/serve/dryrun drivers)."""
+import json
 import os
 import subprocess
 import sys
@@ -8,8 +9,10 @@ import pytest
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
-def _run(args, timeout=600, extra_env=None):
+def _run(args, timeout=600, extra_env=None, drop_env=()):
     env = dict(os.environ)
+    for name in drop_env:
+        env.pop(name, None)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     # Force the CPU platform: with libtpu installed but no TPU attached,
     # leaving the platform unset makes jax's TPU plugin stall ~8 min on
@@ -52,3 +55,27 @@ def test_dryrun_cli_single_cell():
     out = _run(["-m", "repro.launch.dryrun", "--arch", "whisper-base",
                 "--shape", "decode_32k", "--force"], timeout=900)
     assert "ok" in out
+
+
+def _cache_dir_after(env_value):
+    """Run use_compile_cache() in a fresh process; return (returned dir,
+    the dir jax's config holds)."""
+    code = ("import json, jax; "
+            "from repro.launch.compile_cache import use_compile_cache; "
+            "d = use_compile_cache(); "
+            "print(json.dumps([d, jax.config.jax_compilation_cache_dir]))")
+    env = {"JAX_COMPILATION_CACHE_DIR": env_value} if env_value else {}
+    return json.loads(_run(["-c", code], extra_env=env,
+                           drop_env=() if env_value else
+                           ("JAX_COMPILATION_CACHE_DIR",)).strip())
+
+
+def test_compile_cache_follows_the_environment(tmp_path):
+    want = str(tmp_path / "jaxcache")
+    assert _cache_dir_after(want) == [want, want]
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    want = os.path.realpath(os.path.join(ROOT, ".jax_cache"))
+    got, configured = _cache_dir_after(None)
+    assert got == configured == want

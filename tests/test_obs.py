@@ -310,3 +310,21 @@ def test_obs_package_does_not_import_jax():
             "sys.exit(1 if 'jax' in sys.modules else 0)")
     proc = subprocess.run([sys.executable, "-c", code])
     assert proc.returncode == 0
+
+
+def test_cost_report_measure_needs_a_known_device():
+    """A timing is never divided by a peak the device does not have: off
+    the chip (no peak-table row) measure=True raises before timing."""
+    import jax
+    import numpy as np
+    from repro.core.scheduler import DEVICE_PEAKS
+    from repro.core.spmv import SerpensSpMV
+    assert jax.devices()[0].device_kind not in DEVICE_PEAKS
+    rng = np.random.default_rng(0)
+    rows, cols = rng.integers(0, 32, 200), rng.integers(0, 32, 200)
+    op = SerpensSpMV(rows, cols, rng.normal(size=200).astype(np.float32),
+                     (32, 32), backend="xla")
+    rep = op.cost_report()
+    assert "roofline_fraction" not in rep and rep["stream_bytes"] > 0
+    with pytest.raises(KeyError, match="no peaks"):
+        op.cost_report(measure=True)

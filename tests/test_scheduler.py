@@ -74,3 +74,19 @@ class TestTPUModel:
         base = S.tpu_spmv_time(10_000, 10_000, 1_000_000, 1_000_000)[0]
         padded = S.tpu_spmv_time(10_000, 10_000, 1_000_000, 2_000_000)[0]
         assert padded > base
+
+
+class TestDevicePeaks:
+    def test_v5e_row_is_the_published_peak(self):
+        p = S.device_peaks("TPU v5 lite")
+        assert p.hbm_bytes_per_s == 819e9
+        assert p.bf16_flops_per_s == 197e12
+        assert "TPU v5e" in p.source
+
+    def test_unknown_device_is_an_error(self):
+        for kind in ("cpu", "TPU v4", ""):
+            with pytest.raises(KeyError, match="no peaks"):
+                S.device_peaks(kind)
+
+    def test_tpu_model_reads_the_table(self):
+        assert S.TPU_V5E.hbm_bw == S.DEVICE_PEAKS["TPU v5 lite"].hbm_bytes_per_s
