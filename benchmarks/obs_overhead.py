@@ -42,10 +42,11 @@ OVERHEAD_BUDGET = 0.03
 # Instrumentation calls one served request crosses, by primitive.
 # Per-request: submit + result-collect spans, 3 flow emits, 1 dispatch
 # latency observe.  Per-batch (amortized over B coalesced requests):
-# flush/coalesce/dispatch/pack/compute/device-block spans, the flush +
-# batch-size observes, and 3 counter adds.
+# flush/coalesce/dispatch/pack/compute/device-block spans, the guards of
+# the queue-wait and inflight spans (counted as one more span), the flush
+# + batch-size observes, and 3 counter adds.
 PER_REQUEST = {"span": 2, "flow": 3, "observe": 1}
-PER_BATCH = {"span": 6, "observe": 2, "counter": 3}
+PER_BATCH = {"span": 7, "observe": 2, "counter": 3}
 
 
 def _per_call_ns(fn, iters: int) -> float:

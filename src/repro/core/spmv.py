@@ -136,15 +136,11 @@ class SerpensOperator:
     def padded_slots(self) -> int:
         return int(self.plan.idx.size)
 
-    def cost_report(self, *, measure: bool = False,
-                    backend: str | None = None, iters: int = 3) -> dict:
-        """Per-shard cost report (stream bytes, slots, padding),
-        optionally with a measured matvec wall-time and the achieved
-        fraction of the device's HBM peak; raises on a device with no
-        peak-table row.  See :func:`repro.obs.profile.plan_cost_report`."""
+    def cost_report(self) -> dict:
+        """Per-shard cost report counted from the plan (stream bytes,
+        slots, padding).  See :func:`repro.obs.profile.plan_cost_report`."""
         from repro.obs import profile as _profile
-        return _profile.plan_cost_report(
-            self, measure=measure, backend=backend, iters=iters)
+        return _profile.plan_cost_report(self)
 
     def with_mesh(self, mesh, axis: str, partition: str | None = None
                   ) -> "SerpensOperator":

@@ -23,6 +23,10 @@ import jax.numpy as jnp
 
 from repro.core.format import ROW_BITS, COL_MASK, SerpensMatrix
 from repro.kernels import serpens_spmv
+from repro.obs import profile as obs_profile
+
+# JAX's compile events become ``jax-*`` spans while tracing is on.
+obs_profile.install_compile_spans()
 
 # Trace-time dispatch counter: bumped once per run_stream/run_stream_fused
 # *call* (i.e. per stream pass emitted into a trace, not per executed

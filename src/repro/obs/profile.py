@@ -1,38 +1,64 @@
-"""Kernel-profiling hooks: jax.profiler integration + per-plan cost model.
+"""Compile spans and the per-plan cost model.
 
-The paper's results are roofline points — achieved GB/s of A-stream
-traffic against the HBM peak — so a benchmark sweep wants, per run, the
-plan's counted cost (stream bytes, slots, padding) next to its
-*measured* wall-time.  :func:`plan_cost_report` produces exactly that for
-any :class:`~repro.core.spmv.SerpensOperator` (surfaced as
-``op.cost_report()``), and :func:`profiler_trace` wraps a block in a
-``jax.profiler`` trace for TensorBoard/Perfetto-level kernel detail when
-available.
+:func:`install_compile_spans` turns JAX's compile events into ``obs``
+spans, so a trace shows where a call traced, lowered, compiled or loaded
+a program from the persistent cache; :func:`plan_cost_report` counts a
+:class:`~repro.core.spmv.SerpensOperator`'s plan (surfaced as
+``op.cost_report()``): stream bytes, slots, padding.
 
 jax is imported lazily so this module stays importable from numpy-only
 worker processes.
 """
 from __future__ import annotations
 
-import contextlib
-import time
-import warnings
+import threading
+
+from repro.obs.trace import TRACER
+
+# jax.monitoring duration events -> span names.  The backend compile
+# covers a load from the persistent cache, which nests inside it.
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax-trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax-lower",
+    "/jax/core/compile/backend_compile_duration": "jax-compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax-cache-load",
+}
+
+_install_lock = threading.Lock()
+_installed = False
 
 
-def plan_cost_report(op, *, measure: bool = False,
-                     backend: str | None = None, iters: int = 3) -> dict:
+def _hear_compile(event: str, duration: float, **kw) -> None:
+    """Record one compile event as a span ending now, on the compiling
+    thread; JAX names the function for all but the cache load."""
+    if not TRACER.enabled:
+        return
+    name = COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    if "fun_name" in kw:
+        TRACER.event(name, duration, cat="jax", fun=kw["fun_name"])
+    else:
+        TRACER.event(name, duration, cat="jax")
+
+
+def install_compile_spans() -> None:
+    """Register the compile listener with ``jax.monitoring`` once per
+    process; it records spans only while tracing is enabled."""
+    global _installed
+    with _install_lock:
+        if _installed:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_hear_compile)
+        _installed = True
+
+
+def plan_cost_report(op) -> dict:
     """Cost report for one operator's channel-shard plan.
 
     Per shard: nnz, slots, stream bytes, padding ratio and per-lane
-    live-slot imbalance (max/mean) — all counted from the plan.  With
-    ``measure=True`` one matvec is compiled + timed (median of ``iters``)
-    and the report adds the device kind, its HBM peak from
-    :data:`repro.core.scheduler.DEVICE_PEAKS`, the achieved GB/s and its
-    fraction of that peak — the roofline position — plus per-shard
-    measured time attributed proportionally to stream bytes (shards
-    dispatch in one call, so only the total is directly observable).
-    A device with no row in the peak table (the CPU among them) raises
-    ``KeyError`` before anything is timed.
+    live-slot imbalance (max/mean) — all counted from the plan.
     """
     import numpy as np
     from repro.core.format import SENTINEL
@@ -73,52 +99,4 @@ def plan_cost_report(op, *, measure: bool = False,
             (sh["lane_slot_imbalance"] for sh in shards), default=1.0),
         "shards": shards,
     }
-    if measure:
-        import jax
-        from repro.core.scheduler import device_peaks
-        kind = jax.devices()[0].device_kind
-        peak_gbps = device_peaks(kind).hbm_bytes_per_s / 1e9
-        x = np.random.default_rng(0).normal(
-            size=op.shape[1]).astype(np.float32)
-        jax.block_until_ready(op.matvec(x, backend=backend))  # compile
-        times = []
-        for _ in range(max(1, iters)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(op.matvec(x, backend=backend))
-            times.append(time.perf_counter() - t0)
-        times.sort()
-        measured = times[len(times) // 2]
-        report["device_kind"] = kind
-        report["peak_hbm_gbps"] = peak_gbps
-        report["measured_matvec_s"] = measured
-        report["achieved_gbps"] = total_bytes / measured / 1e9
-        report["roofline_fraction"] = report["achieved_gbps"] / peak_gbps
-        for sh in shards:
-            frac = sh["stream_bytes"] / max(total_bytes, 1)
-            sh["measured_s_attributed"] = measured * frac
     return report
-
-
-@contextlib.contextmanager
-def profiler_trace(logdir: str | None):
-    """``jax.profiler`` trace around a block (TensorBoard/Perfetto logs).
-
-    No-op when ``logdir`` is falsy; degrades to a warning + no-op when
-    the profiler is unavailable (e.g. a build without profiling support),
-    so benchmark flags can pass it through unconditionally.
-    """
-    if not logdir:
-        yield
-        return
-    try:
-        import jax
-        jax.profiler.start_trace(str(logdir))
-    except Exception as e:                      # noqa: BLE001 — degrade
-        warnings.warn(f"jax profiler unavailable ({e}); continuing "
-                      f"without a device trace", stacklevel=2)
-        yield
-        return
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -67,9 +67,10 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    """A live duration event; emitted into the buffer at ``__exit__``."""
+    """A live duration event; emitted into the buffer at ``__exit__``,
+    after which ``end_ns`` holds its end."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "end_ns")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -82,7 +83,7 @@ class Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
+        self.end_ns = t1 = time.perf_counter_ns()
         self._tracer._emit("X", self.name, self.cat, self._t0,
                            t1 - self._t0, self.args or None, None)
         return False
@@ -174,13 +175,14 @@ class Tracer:
         self._emit("i", name, cat, time.perf_counter_ns(), 0,
                    args or None, None)
 
-    def event(self, name: str, dur_s: float, cat: str = "app",
-              **args) -> None:
-        """Record an already-measured span ending now (e.g. a worker
-        process's wall-time, shipped home in its result)."""
+    def event(self, name: str, dur_s: float, cat: str = "app", *,
+              end_ns: int | None = None, **args) -> None:
+        """Record an already-measured span ending now, or at ``end_ns``
+        (a ``time.perf_counter_ns()`` reading): e.g. a worker process's
+        wall-time, shipped home in its result."""
         if not self.enabled:
             return
-        end = time.perf_counter_ns()
+        end = time.perf_counter_ns() if end_ns is None else end_ns
         dur = max(0, int(dur_s * 1e9))
         self._emit("X", name, cat, end - dur, dur, args or None, None)
 

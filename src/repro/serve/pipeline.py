@@ -218,6 +218,8 @@ class _Launched:
     out: object               # lazy device array; collect materializes it
     width: int
     t_compute: float          # perf_counter at compute launch
+    t_launched_ns: int = 0    # end of the traced dispatch span (the
+                              # inflight span's start); 0 off the trace
 
 
 _TakeResult = tuple  # (ready_reqs, n_taken, n_deferred)
@@ -886,6 +888,7 @@ class SpMVPipeline:
         re-entry.  Returns (ready_requests, taken, still_deferred).
         """
         with self._lock:
+            t_take = time.perf_counter_ns() if obs.is_enabled() else 0
             if poll_parked:
                 taken = list(self._queue)
                 self._queue.clear()
@@ -955,6 +958,10 @@ class SpMVPipeline:
                             matrix=req.matrix_id)
                 # Re-arm the re-entry in case the unpark raced a re-put.
                 self._listen_for(req.matrix_id, req.expect_content)
+        if t_take:
+            for req in ready_reqs:
+                obs.event("queue-wait", t_take / 1e9 - req.submit_time,
+                          end_ns=t_take, ticket=req.ticket)
         return ready_reqs, len(taken), len(deferred)
 
     def _coalesce(self, ready_reqs: list[SpMVRequest]) -> list[list]:
@@ -982,7 +989,7 @@ class SpMVPipeline:
         n = len(batch)
         width = bucket_width(n, self.max_bucket)
         with obs.span("dispatch", matrix=batch[0].matrix_id, batch=n,
-                      bucket=width):
+                      bucket=width) as dispatch_sp:
             for req in batch:
                 obs.flow_step("request", req.ticket)
             t_comp = time.perf_counter()
@@ -1018,7 +1025,8 @@ class SpMVPipeline:
                 self._m_stream_bytes.add(op.stream_bytes)
                 self._m_batch_size.observe(n)
         return _Launched(batch=batch, op=op, out=out, width=width,
-                         t_compute=t_comp)
+                         t_compute=t_comp,
+                         t_launched_ns=getattr(dispatch_sp, "end_ns", 0))
 
     def _rollback_launch_locked(self, op, batch: list[SpMVRequest]) -> None:
         """Undo one launched batch's counters (lock held) so a failure is
@@ -1034,8 +1042,13 @@ class SpMVPipeline:
         batch, op = launched.batch, launched.op
         n = len(batch)
         with obs.span("collect", matrix=batch[0].matrix_id, batch=n):
-            with obs.span("device-block"):
+            with obs.span("device-block") as block_sp:
                 ys = np.asarray(launched.out, np.float32)
+            t_done = getattr(block_sp, "end_ns", 0)
+            if launched.t_launched_ns and t_done:
+                obs.event("inflight",
+                          (t_done - launched.t_launched_ns) / 1e9,
+                          end_ns=t_done, batch=n, ticket=batch[0].ticket)
             if ys.ndim == 1:
                 ys = ys[:, None]
         done = time.perf_counter()

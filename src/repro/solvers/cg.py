@@ -86,29 +86,28 @@ def conjugate_gradient(op, b, x0=None, tol: float = 1e-6,
     b = jnp.asarray(b, jnp.float32)
     if b.shape != (m,):
         raise ValueError(f"b has shape {b.shape}; expected ({m},)")
-    x_init = (jnp.zeros((m,), jnp.float32) if x0 is None
-              else jnp.asarray(x0, jnp.float32))
     if max_iters is None:
         max_iters = m
     use_fused = _resolve_fused(op, fused)
     tol_eff, _ = precision.effective_tol(
         tol, getattr(op, "value_dtype", "float32"))
-    b_norm = jnp.linalg.norm(b)
-    stop = tol_eff * jnp.maximum(b_norm, 1e-30)
-
-    r_init = b - op.matvec(x_init, backend=backend)
-    rs_init = jnp.dot(r_init, r_init)
 
     with obs.span("conjugate-gradient", cat="solver", n=m,
                   fused=use_fused) as sp:
         d0 = ops.trace_dispatch_count()
-        if use_fused:
-            x, r, rs, iters = _solve_fused(
-                op, x_init, r_init, rs_init, stop, max_iters, backend)
-        else:
-            x, r, rs, iters = _solve_unfused(
-                op, x_init, r_init, rs_init, stop, max_iters, backend)
-        res = float(jnp.sqrt(rs))      # blocks until the solve finishes
+        with obs.span("solver-init", cat="solver"):
+            x_init = (jnp.zeros((m,), jnp.float32) if x0 is None
+                      else jnp.asarray(x0, jnp.float32))
+            b_norm = jnp.linalg.norm(b)
+            stop = tol_eff * jnp.maximum(b_norm, 1e-30)
+            r_init = b - op.matvec(x_init, backend=backend)
+            rs_init = jnp.dot(r_init, r_init)
+        solve = _solve_fused if use_fused else _solve_unfused
+        with obs.span("solver-launch", cat="solver"):
+            x, r, rs, iters = solve(op, x_init, r_init, rs_init, stop,
+                                    max_iters, backend)
+        with obs.span("solver-wait", cat="solver"):
+            res = float(jnp.sqrt(rs))      # blocks until the solve finishes
         sp.args.update(iterations=int(iters), residual=res,
                        stream_dispatches=ops.trace_dispatch_count() - d0)
     return CGResult(x=x, iterations=int(iters), residual=res,

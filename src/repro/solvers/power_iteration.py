@@ -84,16 +84,22 @@ def pagerank(op, damping: float = 0.85, tol: float = 1e-9,
     use_fused = _resolve_fused(op, fused)
     tol_eff, _ = precision.effective_tol(
         tol, getattr(op, "value_dtype", "float32"))
-    r_init = (jnp.full((n,), 1.0 / n, jnp.float32) if r0 is None
-              else jnp.asarray(r0, jnp.float32))
 
     with obs.span("pagerank", cat="solver", n=n, damping=float(damping),
                   fused=use_fused) as sp:
         d0 = ops.trace_dispatch_count()
+        with obs.span("solver-init", cat="solver"):
+            r_init = (jnp.full((n,), 1.0 / n, jnp.float32) if r0 is None
+                      else jnp.asarray(r0, jnp.float32))
+            if use_fused:
+                mask2 = op.to_acc_layout(jnp.ones((n,), jnp.float32))
+                consts = jnp.array([[damping, n]], jnp.float32)
+                state0 = (op.to_acc_layout(r_init),
+                          jnp.full((1, 1), jnp.inf, jnp.float32),
+                          jnp.int32(0))
+            else:
+                state0 = (r_init, jnp.float32(jnp.inf), jnp.int32(0))
         if use_fused:
-            mask2 = op.to_acc_layout(jnp.ones((n,), jnp.float32))
-            consts = jnp.array([[damping, n]], jnp.float32)
-
             def cond(state):
                 _, delta11, it = state
                 return (delta11[0, 0] > tol_eff) & (it < max_iters)
@@ -105,11 +111,9 @@ def pagerank(op, damping: float = 0.85, tol: float = 1e-9,
                     extras=(r2, mask2, consts), backend=backend)
                 return r_new, delta11, it + 1
 
-            r2, delta11, iters = jax.lax.while_loop(
-                cond, body, (op.to_acc_layout(r_init),
-                             jnp.full((1, 1), jnp.inf, jnp.float32),
-                             jnp.int32(0)))
-            r, delta = op.from_acc_layout(r2), float(delta11[0, 0])
+            with obs.span("solver-launch", cat="solver"):
+                r2, delta11, iters = jax.lax.while_loop(cond, body, state0)
+                r, delta = op.from_acc_layout(r2), delta11[0, 0]
         else:
             def cond(state):
                 _, delta, it = state
@@ -124,8 +128,9 @@ def pagerank(op, damping: float = 0.85, tol: float = 1e-9,
                 delta = jnp.sum(jnp.abs(r_new - r))
                 return r_new, delta, it + 1
 
-            r, delta, iters = jax.lax.while_loop(
-                cond, body, (r_init, jnp.float32(jnp.inf), jnp.int32(0)))
+            with obs.span("solver-launch", cat="solver"):
+                r, delta, iters = jax.lax.while_loop(cond, body, state0)
+        with obs.span("solver-wait", cat="solver"):
             delta = float(delta)       # blocks until the solve finishes
         sp.args.update(iterations=int(iters), residual=delta,
                        stream_dispatches=ops.trace_dispatch_count() - d0)
@@ -160,15 +165,24 @@ def power_iteration(op, tol: float = 1e-6, max_iters: int = 200,
     use_fused = _resolve_fused(op, fused)
     tol_eff, _ = precision.effective_tol(
         tol, getattr(op, "value_dtype", "float32"))
-    if v0 is None:
-        v_init = jnp.ones((n,), jnp.float32) / jnp.sqrt(n)
-    else:
-        v_init = jnp.asarray(v0, jnp.float32)
-        v_init = v_init / jnp.linalg.norm(v_init)
 
     with obs.span("power-iteration", cat="solver", n=n,
                   fused=use_fused) as sp:
         d0 = ops.trace_dispatch_count()
+        with obs.span("solver-init", cat="solver"):
+            if v0 is None:
+                v_init = jnp.ones((n,), jnp.float32) / jnp.sqrt(n)
+            else:
+                v_init = jnp.asarray(v0, jnp.float32)
+                v_init = v_init / jnp.linalg.norm(v_init)
+            if use_fused:
+                state0 = (op.to_acc_layout(v_init),
+                          jnp.zeros((1, 1), jnp.float32),
+                          jnp.full((1, 1), jnp.inf, jnp.float32),
+                          jnp.int32(0))
+            else:
+                state0 = (v_init, jnp.float32(0.0), jnp.float32(jnp.inf),
+                          jnp.int32(0))
         if use_fused:
             def cond(state):
                 _, _, res11, it = state
@@ -181,13 +195,11 @@ def power_iteration(op, tol: float = 1e-6, max_iters: int = 200,
                     extras=(v2,), backend=backend)
                 return v_new, lam11, res11, it + 1
 
-            v2, lam11, res11, iters = jax.lax.while_loop(
-                cond, body,
-                (op.to_acc_layout(v_init),
-                 jnp.zeros((1, 1), jnp.float32),
-                 jnp.full((1, 1), jnp.inf, jnp.float32), jnp.int32(0)))
-            v, lam, res = (op.from_acc_layout(v2), lam11[0, 0],
-                           float(res11[0, 0]))
+            with obs.span("solver-launch", cat="solver"):
+                v2, lam11, res11, iters = jax.lax.while_loop(
+                    cond, body, state0)
+                v, lam, res = (op.from_acc_layout(v2), lam11[0, 0],
+                               res11[0, 0])
         else:
             def cond(state):
                 _, _, res, it = state
@@ -202,10 +214,9 @@ def power_iteration(op, tol: float = 1e-6, max_iters: int = 200,
                 v_new = jnp.where(nrm > 0, av / jnp.maximum(nrm, 1e-30), v)
                 return v_new, lam, res, it + 1
 
-            v, lam, res, iters = jax.lax.while_loop(
-                cond, body,
-                (v_init, jnp.float32(0.0), jnp.float32(jnp.inf),
-                 jnp.int32(0)))
+            with obs.span("solver-launch", cat="solver"):
+                v, lam, res, iters = jax.lax.while_loop(cond, body, state0)
+        with obs.span("solver-wait", cat="solver"):
             res = float(res)           # blocks until the solve finishes
         sp.args.update(iterations=int(iters), residual=res,
                        stream_dispatches=ops.trace_dispatch_count() - d0)
