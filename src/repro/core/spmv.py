@@ -61,11 +61,15 @@ class SerpensOperator:
                     f"plan has {plan.num_shards} shards but mesh axis "
                     f"{axis!r} has {n} devices")
             sh = jax.NamedSharding(mesh, P(axis))
-            self._idx = jax.device_put(plan.idx, sh)
-            self._val = jax.device_put(plan.val, sh)
-            self._seg = jax.device_put(plan.seg_ids, sh)
-            self._seg_chunk = jax.device_put(
-                plan.seg_ids[:, ::cfg.tiles_per_chunk], sh)
+            # One length for the stack: the shorter shards end in zero
+            # slots.
+            length = max(map(ops.row_slots, plan.shards))
+            ordered = [ops.row_order(sm, length) for sm in plan.shards]
+            self._dev = ops.ShardArrays(
+                *(jax.device_put(a, sh) for a in (
+                    plan.idx, plan.val,
+                    plan.seg_ids[:, ::cfg.tiles_per_chunk],
+                    *map(np.stack, zip(*ordered)))))
             self._aux = tuple(jax.device_put(a, sh) for a in
                               (plan.aux_rows, plan.aux_cols, plan.aux_vals))
             self._sharded_fns = {}
@@ -75,8 +79,7 @@ class SerpensOperator:
                 (jnp.asarray(sm.aux_rows), jnp.asarray(sm.aux_cols),
                  jnp.asarray(sm.aux_vals)) if sm.n_aux else None
                 for sm in plan.shards]
-        held = ([self._idx, self._val, self._seg, self._seg_chunk,
-                 *self._aux] if mesh is not None else
+        held = ([*self._dev, *self._aux] if mesh is not None else
                 [a for dev in self._shards for a in dev]
                 + [a for aux in self._auxs if aux is not None for a in aux])
         if self._row_perm is not None:
@@ -113,8 +116,8 @@ class SerpensOperator:
     @property
     def device_bytes(self) -> int:
         """Bytes of the device buffers this operator holds resident (the
-        streamed idx/val/seg arrays plus the aux spill triples) — what
-        the registry's byte budget charges for a live binding."""
+        Serpens stream, its row-ordered copy and the aux spill triples) —
+        what the registry's byte budget charges for a live binding."""
         return self._device_bytes
 
     @property
@@ -138,9 +141,11 @@ class SerpensOperator:
 
     def cost_report(self) -> dict:
         """Per-shard cost report counted from the plan (stream bytes,
-        slots, padding).  See :func:`repro.obs.profile.plan_cost_report`."""
+        slots, padding) and the executor path of the bound backend.  See
+        :func:`repro.obs.profile.plan_cost_report`."""
         from repro.obs import profile as _profile
-        return _profile.plan_cost_report(self)
+        return {**_profile.plan_cost_report(self),
+                "executor_path": ops.executor_path(self.backend)}
 
     def with_mesh(self, mesh, axis: str, partition: str | None = None
                   ) -> "SerpensOperator":
@@ -264,9 +269,8 @@ class SerpensOperator:
         plan, cfg = self.plan, self.config
         kp = plan.num_segments_local * cfg.segment_width
         xp = jnp.pad(x, (0, kp - x.shape[0]))
-        idx, val, seg_t, seg_c = self._shards[0]
         return ops.run_stream_fused(
-            idx, val, seg_t, seg_c, xp, epilogue=epilogue, extras=extras,
+            self._shards[0], xp, epilogue=epilogue, extras=extras,
             num_rows_padded=plan.out_rows_padded,
             segment_width=cfg.segment_width,
             tiles_per_chunk=cfg.tiles_per_chunk,
@@ -285,8 +289,7 @@ class SerpensOperator:
 
     def _shard_acc(self, dev, aux, xl, run):
         """One shard's accumulate + its aux-spill epilogue against local x."""
-        idx, val, seg_t, seg_c = dev
-        acc = run(idx, val, seg_t, seg_c, xl)
+        acc = run(dev, xl)
         if aux is not None:
             ar, ac, av = aux
             contrib = av * xl[ac] if xl.ndim == 1 else av[:, None] * xl[ac]
@@ -343,9 +346,9 @@ class SerpensOperator:
         # would retrace and recompile the whole program.
         f = self._sharded_fns.get(backend)
         if f is None:
-            def body(idx, val, seg_t, seg_c, ar, ac, av, xv):
+            def body(dev, ar, ac, av, xv):
                 xl = xv[0] if col else xv
-                acc = self._shard_acc((idx[0], val[0], seg_t[0], seg_c[0]),
+                acc = self._shard_acc(jax.tree.map(lambda a: a[0], dev),
                                       (ar[0], ac[0], av[0]), xl, run)
                 if col:
                     return jax.lax.psum(acc, axis)
@@ -353,12 +356,11 @@ class SerpensOperator:
 
             f = jax.jit(compat.shard_map(
                 body, mesh=self.mesh,
-                in_specs=(P(axis),) * 7 + (x_spec,),
+                in_specs=(P(axis),) * 4 + (x_spec,),
                 out_specs=P() if col else P(axis),
                 check_rep=False))  # pallas_call has no replication rule
             self._sharded_fns[backend] = f
-        acc = f(self._idx, self._val, self._seg, self._seg_chunk,
-                *self._aux, xp)
+        acc = f(self._dev, *self._aux, xp)
         if col:
             return self._finish(acc)
         acc = acc[:, :plan.block_m]

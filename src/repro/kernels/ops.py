@@ -2,10 +2,11 @@
 
 Three execution paths, selectable via ``backend=``:
 
-  * ``"xla"``       — the Serpens stream processed as one vectorized
-                      gather/scatter in plain XLA.  It compiles for the
-                      TPU (v5e) at published matrix sizes and is the
-                      default on every platform.
+  * ``"xla"``       — the stream's live slots in row order: one gather
+                      of x, then each row's products summed by a
+                      segmented scan (no scatter over the non-zeros).  It
+                      compiles for the TPU (v5e) at published matrix
+                      sizes and is the default on every platform.
   * ``"pallas"``    — the hand kernel (``serpens_spmv.py``).  On a TPU it
                       is compiled for real, never interpreted; Mosaic does
                       not lower its gather/scatter yet, so there it raises
@@ -17,11 +18,15 @@ Three execution paths, selectable via ``backend=``:
 from __future__ import annotations
 
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
+from typing import NamedTuple
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.format import ROW_BITS, COL_MASK, SerpensMatrix
+from repro.core.format import COL_MASK, ROW_BITS, SENTINEL, SerpensMatrix
 from repro.kernels import serpens_spmv
 from repro.obs import profile as obs_profile
 
@@ -46,52 +51,184 @@ def _count_dispatch() -> None:
     _trace_dispatches += 1
 
 
-def _decode(idx, seg_ids_tile, segment_width, lanes):
-    """Decode the packed stream: global rows/cols + live mask."""
-    live = idx != -1
-    rows_local = jnp.where(live, (idx >> ROW_BITS) & COL_MASK, 0)
-    cols_local = jnp.where(live, idx & COL_MASK, 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 2)
-    rows = rows_local * lanes + lane
-    cols = seg_ids_tile[:, None, None] * segment_width + cols_local
-    return live, rows, cols
+# -- the row-ordered XLA executor ---------------------------------------
+# Bind time (device_arrays) lays each shard's live slots out in
+# destination-row order.  Per call the products vals * x[cols] are formed
+# in that order and each row's consecutive products are summed by a
+# segmented scan, read at the row's last product: nothing scatters over
+# the non-zeros, and each row's sum is fp32 over its own products only.
+
+BLOCK = 128                                  # scan block: one vreg's lanes
+_ROW_START = np.int32(np.iinfo(np.int32).min)  # sign bit of a key
+_COL = np.int32(np.iinfo(np.int32).max)        # the column under it
 
 
-@functools.partial(jax.jit, static_argnames=("num_rows_padded",
-                                             "segment_width"))
-def spmv_stream_xla(idx, val, seg_ids_tile, x_flat, *, num_rows_padded,
-                    segment_width):
-    """Vectorized XLA execution of the Serpens stream (single scatter-add)."""
-    lanes = idx.shape[2]
-    live, rows, cols = _decode(idx, seg_ids_tile, segment_width, lanes)
-    xv = x_flat[cols.reshape(-1)].reshape(cols.shape)
-    # bf16-load / fp32-accumulate: the upcast is exact, the MAC stays f32.
-    contrib = jnp.where(live, val.astype(jnp.float32) * xv, 0.0)
-    acc = jnp.zeros((num_rows_padded,), jnp.float32)
-    return acc.at[rows.reshape(-1)].add(contrib.reshape(-1))
+class ShardArrays(NamedTuple):
+    """One shard's device buffers.
+
+    ``idx``, ``val``, ``seg_chunk``: the Serpens stream the Pallas kernel
+    reads.  ``keys``, ``vals``, ``ends``: the same live slots in row order
+    for the XLA executor (see :func:`row_order`).
+    """
+    idx: jax.Array
+    val: jax.Array
+    seg_chunk: jax.Array
+    keys: jax.Array
+    vals: jax.Array
+    ends: jax.Array
 
 
-@functools.partial(jax.jit, static_argnames=("num_rows_padded",
-                                             "segment_width"))
-def spmm_stream_xla(idx, val, seg_ids_tile, x_mat, *, num_rows_padded,
-                    segment_width):
-    """Multi-vector stream execution: x_mat is (K_padded, N) → (R_padded, N)."""
-    lanes = idx.shape[2]
-    n = x_mat.shape[1]
-    live, rows, cols = _decode(idx, seg_ids_tile, segment_width, lanes)
-    xv = x_mat[cols.reshape(-1)]                       # (T*S*L, N)
-    contrib = (jnp.where(live, val.astype(jnp.float32), 0.0)
-               .reshape(-1)[:, None] * xv)
-    acc = jnp.zeros((num_rows_padded, n), jnp.float32)
-    return acc.at[rows.reshape(-1)].add(contrib)
+def row_slots(sm: SerpensMatrix) -> int:
+    """Length of a shard's row-ordered arrays: its live stream slots plus
+    at least one zero slot, rounded up to :data:`BLOCK`."""
+    return -(-(sm.nnz - sm.n_aux + 1) // BLOCK) * BLOCK
 
 
-def device_arrays(sm: SerpensMatrix):
-    """Move a host SerpensMatrix's stream arrays to device (jnp)."""
+def row_order(sm: SerpensMatrix, length: int | None = None):
+    """A shard's live stream slots in destination-row order (host numpy).
+
+    Rows and columns decode exactly as the Pallas kernel reads the stream
+    (shard-local, virtual rows for balanced plans); the aux spill stays
+    out.  Returns ``(keys, vals, ends)``:
+
+    * ``keys`` int32 ``[length]``: each product's column, with the sign
+      bit set on a row's first product.  ``length`` (by default, and at
+      least, :func:`row_slots`) leaves zero tail slots, each a row of its
+      own.
+    * ``vals`` ``[length]``, in the stream's value dtype.
+    * ``ends`` int32 ``[padded_rows]``: each row's last position; an empty
+      row points at the last (zero) slot.
+
+    Within a row the stream's order is kept.  A row lives in one lane, so
+    each lane is sorted by its lane-local row on its own (a 16-bit radix
+    sort over a lane's slots, in parallel threads) and then placed at its
+    rows' offsets.
+    """
     cfg = sm.config
-    seg_chunks = sm.seg_ids[:: cfg.tiles_per_chunk]
-    return (jnp.asarray(sm.idx), jnp.asarray(sm.val),
-            jnp.asarray(sm.seg_ids), jnp.asarray(seg_chunks))
+    lanes = cfg.lanes
+    idx = sm.idx.reshape(-1, lanes)
+    val = sm.val.reshape(-1, lanes)
+    col0 = np.repeat(sm.seg_ids * cfg.segment_width, cfg.sublanes)
+    per_lane = sm.padded_rows // lanes
+    length = length or row_slots(sm)
+
+    def sort_lane(lane):
+        word = idx[:, lane]
+        at = np.flatnonzero(word != SENTINEL)
+        order = np.argsort((word[at] >> ROW_BITS).astype(np.uint16),
+                           kind="stable")
+        at = at[order]
+        word = word[at]
+        local = (word >> ROW_BITS) & COL_MASK
+        return at, word, local, np.bincount(local, minlength=per_lane)
+
+    with ThreadPoolExecutor(min(lanes, os.cpu_count() or 1)) as pool:
+        by_lane = list(pool.map(sort_lane, range(lanes)))
+        counts = np.stack([b[3] for b in by_lane], axis=1).reshape(-1)
+        ends = np.cumsum(counts, dtype=np.int32) - 1
+        starts = ends - counts + 1
+        keys = np.full(length, _ROW_START, np.int32)
+        vals = np.zeros(length, sm.val.dtype)
+
+        def place(lane):
+            at, word, local, count = by_lane[lane]
+            shift = starts[lane::lanes] - (np.cumsum(count) - count)
+            pos = np.arange(at.size) + shift[local]
+            keys[pos] = col0[at] + (word & COL_MASK)
+            vals[pos] = val[at, lane]
+
+        list(pool.map(place, range(lanes)))
+    keys[starts[counts > 0]] |= _ROW_START
+    ends[counts == 0] = length - 1
+    return keys, vals, ends
+
+
+def _shift(a, d, fill):
+    """``a`` moved ``d`` places along its last axis, ``fill`` shifted in."""
+    pad = [(0, 0)] * (a.ndim - 1) + [(d, 0)]
+    return jnp.pad(a[..., :-d], pad, constant_values=fill)
+
+
+def _segmented_scan(v, start):
+    """Inclusive sum of ``v`` along its last axis, restarted at ``start``.
+
+    Hillis–Steele steps inside blocks of :data:`BLOCK`; the same scan over
+    the blocks' last values then carries a run across block edges.
+    ``start`` (bool, the last axis alone) broadcasts over ``v``'s leading
+    axes.
+    """
+    *lead, n = v.shape
+    nb = -(-n // BLOCK)
+    pad = nb * BLOCK - n
+    v = jnp.pad(v, [(0, 0)] * len(lead) + [(0, pad)])
+    v = v.reshape(*lead, nb, BLOCK)
+    f = jnp.pad(start, (0, pad), constant_values=True).reshape(nb, BLOCK)
+    d = 1
+    while d < min(n, BLOCK):
+        v = jnp.where(f, v, v + _shift(v, d, 0.0))
+        f = f | _shift(f, d, False)
+        d *= 2
+    if nb > 1:
+        run = _segmented_scan(v[..., -1], f[:, -1])
+        carry = _shift(run, 1, 0.0)
+        v = v + jnp.where(f, 0.0, carry[..., None])
+    return v.reshape(*lead, nb * BLOCK)[..., :n]
+
+
+def _check_rows(ends, num_rows_padded):
+    if ends.shape != (num_rows_padded,):
+        raise ValueError(f"ends has shape {ends.shape}, expected "
+                         f"({num_rows_padded},)")
+
+
+def _row_sums(keys, vals, xg, ends):
+    """fp32 row sums of ``vals * xg`` along the row-ordered slots."""
+    prod = vals.astype(jnp.float32) * xg
+    return jnp.take(_segmented_scan(prod, keys < 0), ends)
+
+
+@functools.partial(jax.jit, static_argnames=("num_rows_padded",
+                                             "segment_width"))
+def spmv_stream_xla(keys, vals, ends, x_flat, *, num_rows_padded,
+                    segment_width):
+    """Row-ordered XLA execution of one shard (see :func:`row_order`):
+    ``A @ x`` over the ``num_rows_padded`` rows of ``ends``."""
+    del segment_width                      # the keys hold global columns
+    _check_rows(ends, num_rows_padded)
+    # bf16-load / fp32-accumulate: the upcast is exact, the sums are f32.
+    return _row_sums(keys, vals, x_flat[keys & _COL], ends)
+
+
+@functools.partial(jax.jit, static_argnames=("num_rows_padded",
+                                             "segment_width"))
+def spmm_stream_xla(keys, vals, ends, x_mat, *, num_rows_padded,
+                    segment_width):
+    """Multi-vector :func:`spmv_stream_xla`: x_mat is (K_padded, N) →
+    (R_padded, N).  One gather fetches each product's N-wide row of x;
+    the vectors' row sums then run one after another, so the scan stays
+    one-dimensional (its compile and its temporaries do not grow with N).
+    """
+    del segment_width
+    _check_rows(ends, num_rows_padded)
+    xg = jnp.take(x_mat.T, keys & _COL, axis=1)            # (N, L)
+    return jax.lax.map(lambda g: _row_sums(keys, vals, g, ends), xg).T
+
+
+def device_arrays(sm: SerpensMatrix) -> ShardArrays:
+    """Move a host SerpensMatrix to the device: its stream and its
+    row-ordered copy (:func:`row_order`)."""
+    cfg = sm.config
+    return ShardArrays(
+        jnp.asarray(sm.idx), jnp.asarray(sm.val),
+        jnp.asarray(sm.seg_ids[:: cfg.tiles_per_chunk]),
+        *(jnp.asarray(a) for a in row_order(sm)))
+
+
+def executor_path(backend: str | None = None) -> str:
+    """How ``backend`` sums each row's products: ``"row_segmented"`` (the
+    XLA executor) or ``"scatter"`` (the Pallas kernel's accumulate)."""
+    return ("row_segmented" if resolve_backend(backend) == "xla"
+            else "scatter")
 
 
 def pad_x(x, num_segments, segment_width):
@@ -121,46 +258,45 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def run_stream(idx, val, seg_ids_tile, seg_ids_chunk, x, *, num_rows_padded,
-               segment_width, tiles_per_chunk=1, backend="auto"):
-    """The one backend-dispatch point for executing a Serpens stream.
+def run_stream(arrays: ShardArrays, x, *, num_rows_padded, segment_width,
+               tiles_per_chunk=1, backend="auto"):
+    """The one backend-dispatch point for executing a shard.
 
     Accepts a 1-D x (matvec) or a 2-D ``(K_padded, N)`` x (matmat) already
     padded to ``num_segments * segment_width`` rows, and routes to the XLA
-    stream execution or the Pallas kernel.  Every executor — single-device,
-    per-shard loop, or a ``shard_map`` body — funnels through here, so all
-    four (backend x arity) paths share one definition.
+    row-ordered executor or the Pallas kernel over the Serpens stream.
+    Every executor — single-device, per-shard loop, or a ``shard_map``
+    body — funnels through here, so all four (backend x arity) paths
+    share one definition.
     """
     _count_dispatch()
     backend = resolve_backend(backend)
     if backend == "xla":
-        if x.ndim == 1:
-            return spmv_stream_xla(idx, val, seg_ids_tile, x,
-                                   num_rows_padded=num_rows_padded,
-                                   segment_width=segment_width)
-        return spmm_stream_xla(idx, val, seg_ids_tile, x,
-                               num_rows_padded=num_rows_padded,
-                               segment_width=segment_width)
+        xla = spmv_stream_xla if x.ndim == 1 else spmm_stream_xla
+        return xla(arrays.keys, arrays.vals, arrays.ends, x,
+                   num_rows_padded=num_rows_padded,
+                   segment_width=segment_width)
     if backend == "pallas":
+        idx, val, seg_chunk = arrays.idx, arrays.val, arrays.seg_chunk
         interpret = _interpret()
         if x.ndim == 1:
             return serpens_spmv.spmv_pallas(
-                idx, val, seg_ids_chunk, x.reshape(-1, segment_width),
+                idx, val, seg_chunk, x.reshape(-1, segment_width),
                 num_rows_padded=num_rows_padded,
                 segment_width=segment_width,
                 tiles_per_chunk=tiles_per_chunk, interpret=interpret)
         num_segments = x.shape[0] // segment_width
         return serpens_spmv.spmm_pallas(
-            idx, val, seg_ids_chunk,
+            idx, val, seg_chunk,
             x.reshape(num_segments, segment_width, -1),
             num_rows_padded=num_rows_padded, segment_width=segment_width,
             tiles_per_chunk=tiles_per_chunk, interpret=interpret)
     raise ValueError(f"unknown backend {backend!r}")
 
 
-def run_stream_fused(idx, val, seg_ids_tile, seg_ids_chunk, x, *, epilogue,
-                     extras=(), num_rows_padded, segment_width,
-                     tiles_per_chunk=1, backend="auto"):
+def run_stream_fused(arrays: ShardArrays, x, *, epilogue, extras=(),
+                     num_rows_padded, segment_width, tiles_per_chunk=1,
+                     backend="auto"):
     """One-pass matvec **plus** a fused epilogue — the solver hot path.
 
     ``epilogue(acc2d, *extras) -> tuple of arrays`` runs with the
@@ -169,10 +305,10 @@ def run_stream_fused(idx, val, seg_ids_tile, seg_ids_chunk, x, *, epilogue,
     (:func:`~repro.kernels.serpens_spmv.spmv_fused_pallas`), so one HBM
     pass per solver iteration does the matrix *and* the vector work; on
     the XLA backend it is applied in the same trace immediately after the
-    stream scatter, where XLA fuses it with the accumulator while it is
-    still in registers/cache.  ``extras`` must be arrays of ≥2 dims
-    (scalars as (1, 1)); solver vectors travel in (R, LANES) accumulator
-    layout — a pure reshape of the flat vector for square matrices.
+    row sums, where XLA fuses it with the accumulator.  ``extras`` must
+    be arrays of ≥2 dims (scalars as (1, 1)); solver vectors travel in
+    (R, LANES) accumulator layout — a pure reshape of the flat vector for
+    square matrices.
 
     Returns ``(acc, outs)``: flat ``A @ x`` over padded rows, and the
     epilogue outputs.  Counts as ONE stream dispatch
@@ -181,17 +317,17 @@ def run_stream_fused(idx, val, seg_ids_tile, seg_ids_chunk, x, *, epilogue,
     _count_dispatch()
     extras = tuple(extras)
     backend = resolve_backend(backend)
+    lanes = arrays.idx.shape[-1]
     if backend == "xla":
-        acc = spmv_stream_xla(idx, val, seg_ids_tile, x,
+        acc = spmv_stream_xla(arrays.keys, arrays.vals, arrays.ends, x,
                               num_rows_padded=num_rows_padded,
                               segment_width=segment_width)
-        lanes = idx.shape[2]
         outs = epilogue(acc.reshape(-1, lanes), *extras)
         return acc, tuple(outs)
     if backend == "pallas":
         return serpens_spmv.spmv_fused_pallas(
-            idx, val, seg_ids_chunk, x.reshape(-1, segment_width), extras,
-            epilogue=epilogue, num_rows_padded=num_rows_padded,
-            segment_width=segment_width, tiles_per_chunk=tiles_per_chunk,
-            interpret=_interpret())
+            arrays.idx, arrays.val, arrays.seg_chunk,
+            x.reshape(-1, segment_width), extras, epilogue=epilogue,
+            num_rows_padded=num_rows_padded, segment_width=segment_width,
+            tiles_per_chunk=tiles_per_chunk, interpret=_interpret())
     raise ValueError(f"unknown backend {backend!r}")

@@ -302,6 +302,10 @@ class SpMVPipeline:
         m = self.metrics
         self._m_batches = m.counter(
             "spmv_batches_total", "SpMM dispatches")
+        self._m_executor = m.counter(
+            "executor_calls",
+            "dispatched batches, by how the executor sums each row "
+            "(path=row_segmented|scatter)")
         self._m_vectors = m.counter(
             "spmv_vectors_total", "real vectors (requests) served")
         self._m_stream_bytes = m.counter(
@@ -1021,6 +1025,7 @@ class SpMVPipeline:
                            * jnp.asarray(betas)[None, :])
             with self._lock:
                 self._m_batches.inc()
+                self._m_executor.inc(path=self._executor_path(op))
                 self._m_vectors.add(n)
                 self._m_stream_bytes.add(op.stream_bytes)
                 self._m_batch_size.observe(n)
@@ -1028,10 +1033,14 @@ class SpMVPipeline:
                          t_compute=t_comp,
                          t_launched_ns=getattr(dispatch_sp, "end_ns", 0))
 
+    def _executor_path(self, op) -> str:
+        return kops.executor_path(self.backend or op.backend)
+
     def _rollback_launch_locked(self, op, batch: list[SpMVRequest]) -> None:
         """Undo one launched batch's counters (lock held) so a failure is
         never observable as served traffic."""
         self._m_batches.add(-1)  # repro-lint: disable=stat-lock
+        self._m_executor.add(-1, path=self._executor_path(op))  # repro-lint: disable=stat-lock
         self._m_vectors.add(-len(batch))  # repro-lint: disable=stat-lock
         self._m_stream_bytes.add(-op.stream_bytes)  # repro-lint: disable=stat-lock
 
@@ -1101,6 +1110,7 @@ class SpMVPipeline:
             # economics, so stream-bytes charge iters full passes.
             with self._lock:
                 self._m_batches.inc()
+                self._m_executor.inc(path=kops.executor_path(op.backend))
                 self._m_vectors.add(1)
                 self._m_stream_bytes.add(op.stream_bytes * iters)
                 self._m_batch_size.observe(1)

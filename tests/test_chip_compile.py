@@ -3,16 +3,20 @@
 Each test lowers one program of the serving path at the widths of the G7
 stand-in (soc_pokec: 1.63M rows, ~30.6M nnz; 45,000 tiles of 8 x 128
 slots, above the 32,751 its fp32 plan holds; 8192-wide x segments;
-12,736 lane-local rows) and compiles it with
-the chip's own compiler, so a change that the chip would refuse fails here
-without a chip.  Nothing runs: these say nothing about results or times.
+12,736 lane-local rows; the same live slots in row order for the XLA
+executor) and compiles it with the chip's own compiler, so a change that
+the chip would refuse fails here without a chip.  Nothing runs: these say
+nothing about results or times.  Each XLA program is also read for a
+scatter over the non-zeros, which the row-ordered executor must not hold.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every xdist worker
 imports this file.  The persistent compilation cache is off around the
 compiles (an entry written for a described chip cannot be read back).
 """
+import math
 import os
+import re
 
 import pytest
 
@@ -28,6 +32,7 @@ from repro.solvers.power_iteration import _pagerank_epilogue
 TILES, SUB, LANES, W = 45_000, 8, 128, 8192
 NUM_SEGMENTS = 199                      # ceil(1.63M / 8192)
 ROWS_PADDED = 12_736 * LANES            # lane-local rows x lanes
+NNZ = 30_593_458                        # live slots of the fp32 plan
 HBM_BYTES = 16 * 2**30                  # one v5e chip
 
 
@@ -59,12 +64,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _stream(sharding, tiles=TILES, lead=()):
-    shape = lead + (tiles, SUB, LANES)
-    return (jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding),
-            jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding),
-            jax.ShapeDtypeStruct(lead + (tiles,), jnp.int32,
-                                 sharding=sharding))
+def _shard(sharding, tiles=TILES, nnz=NNZ, rows=ROWS_PADDED, lead=()):
+    """One shard's device arrays (:class:`ops.ShardArrays`) as shapes."""
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=sharding)
+    slots = -(-(nnz + 1) // ops.BLOCK) * ops.BLOCK
+    tile = (tiles, SUB, LANES)
+    return ops.ShardArrays(
+        idx=a(tile, jnp.int32), val=a(tile, jnp.float32),
+        seg_chunk=a((tiles,), jnp.int32), keys=a((slots,), jnp.int32),
+        vals=a((slots,), jnp.float32), ends=a((rows,), jnp.int32))
 
 
 def _fits_one_chip(compiled):
@@ -74,43 +83,58 @@ def _fits_one_chip(compiled):
     assert used < HBM_BYTES, mem
 
 
+def _no_scatter_over_nonzeros(compiled, nnz=NNZ):
+    """No scatter in the program has an update count of the order of the
+    non-zeros (a tenth of them or more)."""
+    hlo = compiled.as_text()
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    for operands in re.findall(r" scatter\(([^)]*)\)", hlo):
+        names = [o.strip().lstrip("%") for o in operands.split(",")]
+        for name in names[2:]:                   # the updates
+            dims = shapes[name].split(",") if shapes[name] else []
+            count = math.prod(int(d) for d in dims)
+            assert count < nnz // 10, (name, shapes[name])
+
+
 def test_spmv_stream_xla_compiles(one_chip):
-    idx, val, seg = _stream(one_chip)
+    dev = _shard(one_chip)
     x = jax.ShapeDtypeStruct((NUM_SEGMENTS * W,), jnp.float32,
                              sharding=one_chip)
     compiled = ops.spmv_stream_xla.lower(
-        idx, val, seg, x, num_rows_padded=ROWS_PADDED,
+        dev.keys, dev.vals, dev.ends, x, num_rows_padded=ROWS_PADDED,
         segment_width=W).compile()
     _fits_one_chip(compiled)
+    _no_scatter_over_nonzeros(compiled)
 
 
 def test_spmm_stream_xla_compiles_at_n16(one_chip):
-    idx, val, seg = _stream(one_chip)
+    dev = _shard(one_chip)
     x = jax.ShapeDtypeStruct((NUM_SEGMENTS * W, 16), jnp.float32,
                              sharding=one_chip)
     compiled = ops.spmm_stream_xla.lower(
-        idx, val, seg, x, num_rows_padded=ROWS_PADDED,
+        dev.keys, dev.vals, dev.ends, x, num_rows_padded=ROWS_PADDED,
         segment_width=W).compile()
     _fits_one_chip(compiled)
+    _no_scatter_over_nonzeros(compiled)
 
 
 def test_fused_pagerank_step_compiles_on_xla(one_chip):
-    idx, val, seg = _stream(one_chip)
+    dev = _shard(one_chip)
     x = jax.ShapeDtypeStruct((NUM_SEGMENTS * W,), jnp.float32,
                              sharding=one_chip)
     acc2 = jax.ShapeDtypeStruct((ROWS_PADDED // LANES, LANES), jnp.float32,
                                 sharding=one_chip)
     consts = jax.ShapeDtypeStruct((1, 2), jnp.float32, sharding=one_chip)
 
-    def step(idx, val, seg, x, r2, mask2, consts):
+    def step(dev, x, r2, mask2, consts):
         return ops.run_stream_fused(
-            idx, val, seg, seg, x, epilogue=_pagerank_epilogue,
+            dev, x, epilogue=_pagerank_epilogue,
             extras=(r2, mask2, consts), num_rows_padded=ROWS_PADDED,
             segment_width=W, backend="xla")
 
-    compiled = jax.jit(step).lower(idx, val, seg, x, acc2, acc2,
-                                   consts).compile()
+    compiled = jax.jit(step).lower(dev, x, acc2, acc2, consts).compile()
     _fits_one_chip(compiled)
+    _no_scatter_over_nonzeros(compiled)
 
 
 def test_row_plan_shard_map_compiles_on_four_chips(topo):
@@ -118,19 +142,21 @@ def test_row_plan_shard_map_compiles_on_four_chips(topo):
     sharded = NamedSharding(mesh, P("chips"))
     n = mesh.size
     rows_per_shard = -(-ROWS_PADDED // n // LANES) * LANES
-    idx, val, seg = _stream(sharded, tiles=-(-TILES // n), lead=(n,))
+    dev = _shard(sharded, tiles=-(-TILES // n), nnz=-(-NNZ // n),
+                 rows=rows_per_shard, lead=(n,))
     x = jax.ShapeDtypeStruct((NUM_SEGMENTS * W,), jnp.float32,
                              sharding=NamedSharding(mesh, P()))
 
-    def body(idx, val, seg, x):
-        return ops.run_stream(idx[0], val[0], seg[0], seg[0], x,
+    def body(dev, x):
+        return ops.run_stream(jax.tree.map(lambda a: a[0], dev), x,
                               num_rows_padded=rows_per_shard,
                               segment_width=W, backend="xla")[None]
 
-    f = compat.shard_map(body, mesh=mesh, in_specs=(P("chips"),) * 3 + (P(),),
+    f = compat.shard_map(body, mesh=mesh, in_specs=(P("chips"), P()),
                          out_specs=P("chips"), check_rep=False)
-    compiled = jax.jit(f).lower(idx, val, seg, x).compile()
+    compiled = jax.jit(f).lower(dev, x).compile()
     _fits_one_chip(compiled)
+    _no_scatter_over_nonzeros(compiled, nnz=-(-NNZ // n))
 
 
 @pytest.mark.xfail(
@@ -139,10 +165,10 @@ def test_row_plan_shard_map_compiles_on_four_chips(topo):
            "divisible by 8 in its second-to-last dimension; behind it the "
            "1-D gather xseg[cols] and the scatter-add do not lower")
 def test_spmv_pallas_compiles_for_the_chip(one_chip):
-    tiles = 1024
-    idx, val, seg = _stream(one_chip, tiles=tiles)
+    dev = _shard(one_chip, tiles=1024)
     x2d = jax.ShapeDtypeStruct((NUM_SEGMENTS, W), jnp.float32,
                                sharding=one_chip)
     serpens_spmv.spmv_pallas.lower(
-        idx, val, seg, x2d, num_rows_padded=ROWS_PADDED, segment_width=W,
+        dev.idx, dev.val, dev.seg_chunk, x2d, num_rows_padded=ROWS_PADDED,
+        segment_width=W,
         tiles_per_chunk=1, interpret=False).compile()

@@ -121,8 +121,11 @@ class TestErrorBound:
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_backends_bitwise_agree_per_dtype(self, dtype):
-        """xla and pallas share the fp32 accumulation order, so they agree
-        bitwise at *both* stream precisions."""
+        """xla sums each row along its row-ordered slots, pallas in stream
+        order (a scatter-add).  At both stream precisions they agree
+        bitwise on rows of at most two products (fp32 addition commutes)
+        and elsewhere to the rounding of an fp32 sum of the row's
+        products, 2·γ_n·(|Â| @ |x|) for the longest row n."""
         rows, cols, vals, shape = matrix_family("uniform", seed=19)
         plan = P.make_plan(rows, cols, vals, shape, cfg_at(CFG, dtype),
                            P.PlanSpec())
@@ -130,9 +133,18 @@ class TestErrorBound:
         op = SerpensOperator(plan, backend="auto")
         x = np.random.default_rng(23).normal(size=shape[1]).astype(
             np.float32)
-        np.testing.assert_array_equal(np.asarray(op.matvec(x, backend="xla")),
-                                      np.asarray(op.matvec(x,
-                                                           backend="pallas")))
+        y_xla = np.asarray(op.matvec(x, backend="xla"), np.float64)
+        y_pallas = np.asarray(op.matvec(x, backend="pallas"), np.float64)
+        r, c, v = plan.to_coo()
+        a_abs = np.zeros(shape)
+        np.add.at(a_abs, (r, c), np.abs(v.astype(np.float64)))
+        counts = (a_abs != 0).sum(axis=1)
+        np.testing.assert_array_equal(y_xla[counts <= 2],
+                                      y_pallas[counts <= 2])
+        n = int(counts.max()) + 1
+        gamma = n * 2.0 ** -24 / (1 - n * 2.0 ** -24)
+        assert np.all(np.abs(y_xla - y_pallas)
+                      <= 2 * gamma * (a_abs @ np.abs(x)))
 
 
 class TestBitIdentityPerDtype:
