@@ -3,10 +3,29 @@
     python3 bench/run.py --workload pokec.pagerank --seed 7 --seconds 40 \
         --trace 0
 
-A cell names a configuration (``bench/configs/<config>.json``: the
-matrix stand-in, the registry and service settings, the limits of the
-correctness check) and a traffic mix (``bench/traffic/<mix>.json``, read
-by ``bench/loadgen.py``).  Each metric is a reader of its own,
+A cell names a configuration (``bench/configs/<config>.json``) and a
+traffic mix (``bench/traffic/<mix>.json``, read by ``bench/loadgen.py``).
+A configuration holds:
+
+* ``matrix``: the stand-in's generator (``bench/generators/<name>.py``)
+  and its sizes; ``value_dtype``: the stream's value type;
+* ``registry``: ``byte_budget`` and ``verify`` of ``MatrixRegistry``;
+  ``service``: ``max_bucket`` of ``SpMVService``;
+* ``limits``: the limit of each number the correctness check compares;
+* optionally ``plan``: ``{"partition": "single"|"row"|"col"}`` (and
+  ``lane_assign``), the ``PlanSpec`` that ``put`` encodes, with one shard
+  for each of the cell's ``chips``.  Without it the matrix is put with
+  the default plan.
+
+A cell on more than one chip is served on a mesh of the first ``chips``
+devices (axis ``chips``): ``registry.get`` binds the plan to it, or
+repartitions the default plan by rows, and ``SpMVService`` serves on it,
+as ``chip_smoke.serve_mesh`` does.  A ``solve`` mix runs there too:
+``submit_solve`` binds the operator with the service's mesh, and the
+solvers run a mesh-bound operator unfused, one sharded SpMV an iteration.
+
+Device numbers (busy and idle time, the roofline's peak, peak memory)
+are read over the cell's chips.  Each metric is a reader of its own,
 ``bench/end_to_end/<name>.py`` or ``bench/layer_metrics/<name>.py``,
 found by the metric's name; a reader that finds nothing returns None and
 the metric is left out.  A cell, mix, configuration or metric is added as
@@ -20,8 +39,8 @@ window produced, and each compared number is printed beside its limit.
 per-layer metrics instead of the end-to-end ones.
 
 The last line of standard output is one JSON object.  Without a TPU, or
-with fewer chips than the cell asks for, or outside a checkout that
-holds ``src/repro``, the run exits non-zero and prints no result.
+with fewer TPU chips than the cell's ``chips``, or outside a checkout
+that holds ``src/repro``, the run exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -46,6 +65,8 @@ ROOT = BENCH.parent
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+REFERENCE_THREADS = 8       # after the window: the reference's own threads
+MESH_AXIS = "chips"         # the mesh of a cell on more than one chip
 
 
 def load_json(kind: str, name: str) -> dict:
@@ -101,6 +122,7 @@ class Run:
     nnz: int                    # non-zeros of the matrix, from its triples
     shape: tuple
     value_dtype: str
+    chips: int = 1              # the cell's devices
     gen_s: float = 0.0
     put_s: float = 0.0
     warm_s: float = 0.0
@@ -195,26 +217,26 @@ def check(run: Run, rows, cols, vals, pool, log) -> dict:
     checks = {"missing": {"value": sum(r.error is not None for r in reqs),
                           "limit": 0}}
     t0 = time.perf_counter()
-    ref = reference.Reference(rows, cols, vals, run.shape)
-    if run.mix["kind"] == "solve":
-        r_star = ref.pagerank(run.mix["params"]["damping"])
-        gap = max((reference.l1_gap(r.y, r_star) for r in run.served),
-                  default=math.inf)
-        checks["pagerank_l1_gap"] = {"value": gap,
-                                     "limit": limits["pagerank_l1_gap"]}
-    else:
-        used = sorted({r.vector for r in run.served})
-        gap = 0.0 if used else math.inf
-        for i in range(0, len(used), 16):       # blocks of 16 vectors
-            block = used[i:i + 16]
-            ys, mags = ref.products(pool[block])
-            at = {j: n for n, j in enumerate(block)}
-            for r in run.served:
-                if r.vector in at:
-                    n = at[r.vector]
-                    gap = max(gap, reference.rel_gap(r.y, ys[n], mags[n]))
-        checks["spmv_rel_gap"] = {"value": gap,
-                                  "limit": limits["spmv_rel_gap"]}
+    with reference.Reference(rows, cols, vals, run.shape,
+                             threads=REFERENCE_THREADS) as ref:
+        if run.mix["kind"] == "solve":
+            r_star = ref.pagerank(run.mix["params"]["damping"])
+            gap = max((reference.l1_gap(r.y, r_star) for r in run.served),
+                      default=math.inf)
+            checks["pagerank_l1_gap"] = {"value": gap,
+                                         "limit": limits["pagerank_l1_gap"]}
+        else:
+            used = sorted({r.vector for r in run.served})
+            gap = 0.0 if used else math.inf
+            for i in range(0, len(used), 16):       # blocks of 16 vectors
+                block = used[i:i + 16]
+                ys, mags = ref.products(pool[block])
+                at = {j: n for n, j in enumerate(block)}
+                gap = max([gap, *ref.rel_gaps(
+                    (r.y, ys[at[r.vector]], mags[at[r.vector]])
+                    for r in run.served if r.vector in at)])
+            checks["spmv_rel_gap"] = {"value": gap,
+                                      "limit": limits["spmv_rel_gap"]}
     log(f"reference_s={time.perf_counter() - t0:.3f} "
         f"compared={len(run.served)}")
     return checks
@@ -228,7 +250,10 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
     Takes no notice of the platform: the caller checks for the chip.
     ``config`` and ``mix`` stand in for the cell's files (tests)."""
     import jax
+    import numpy as np
+    from jax.sharding import Mesh
     from repro import obs
+    from repro.core.partition import PlanSpec
     from repro.core.registry import MatrixRegistry
     from repro.serve.spmv_service import SpMVService
     import loadgen
@@ -236,7 +261,19 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
     cell = next(c for c in bench["workloads"] if c["name"] == cell_name)
     config = config or load_json("configs", cell["config"])
     mix = mix or load_json("traffic", cell["traffic"])
-    dev = jax.devices()[0]
+    devices = jax.devices()[:cell["chips"]]
+    if len(devices) < cell["chips"]:
+        raise ValueError(f"{cell_name} needs {cell['chips']} devices; "
+                         f"JAX has {len(devices)}")
+    plan = config.get("plan")
+    put_kw = ({"spec": PlanSpec(num_shards=len(devices), **plan)}
+              if plan else {})
+    bind_kw = {}
+    if len(devices) > 1:
+        bind_kw = {"mesh": Mesh(np.array(devices), (MESH_AXIS,)),
+                   "axis": MESH_AXIS,
+                   "partition": plan["partition"] if plan else None}
+    dev = devices[0]
 
     t = time.perf_counter()
     gen = load_reader("generators", config["matrix"]["generator"])
@@ -244,7 +281,7 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
     pool = _pool(mix, shape[1], seed)
     run = Run(cell=cell_name, config=config, mix=mix, seconds=seconds,
               nnz=int(rows.size), shape=tuple(shape),
-              value_dtype=config["value_dtype"],
+              value_dtype=config["value_dtype"], chips=len(devices),
               gen_s=time.perf_counter() - t)
     log(f"setup generate_s={run.gen_s:.3f} nnz={run.nnz} shape={shape}")
 
@@ -252,15 +289,18 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
     reg = MatrixRegistry(byte_budget=int(reg_cfg["byte_budget"]),
                          verify=reg_cfg["verify"])
     t = time.perf_counter()
-    mid = reg.put(rows, cols, vals, shape, value_dtype=run.value_dtype)
-    op = reg.get(mid)
+    mid = reg.put(rows, cols, vals, shape, value_dtype=run.value_dtype,
+                  **put_kw)
+    op = reg.get(mid, **bind_kw)
     run.put_s = time.perf_counter() - t
     run.op_nnz, run.op_padded_slots = int(op.nnz), int(op.padded_slots)
     log(f"setup put_s={run.put_s:.3f} slots={run.op_padded_slots} "
-        f"stream_bytes={op.stream_bytes}")
+        f"stream_bytes={op.stream_bytes} plan={op.plan.spec.partition}:"
+        f"{op.plan.num_shards} stream_devices={len(op.stream_devices)}")
     del op
 
-    svc = SpMVService(reg, max_bucket=int(config["service"]["max_bucket"]))
+    svc = SpMVService(reg, max_bucket=int(config["service"]["max_bucket"]),
+                      **bind_kw)
     compiles = _CompileCounter()
     t = time.perf_counter()
     compiles.on = True
@@ -313,11 +353,15 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
             trace_reduce.host_events(profile), perf_sync)
         acts, run.spans = _mirrored_spans(obs, offset)
         obs.clear()
-        run.trace = trace_reduce.reduce_profile(profile, extra_host=acts)
+        run.trace = trace_reduce.reduce_profile(
+            profile, extra_host=acts, device_ids=[d.id for d in devices])
         run.peaks = device_peaks(dev.device_kind)
+        log(f"busy_s_by_device={run.trace['busy_s_by_device']} "
+            f"device_ids={run.trace['device_ids']}")
         log(f"idle_by_activity={json.dumps(run.trace['idle_by_activity'])}")
-    stats = dev.memory_stats() or {}
-    peak_bytes = int(stats.get("peak_bytes_in_use", 0))
+    peak_bytes = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                  for d in devices]
+    log(f"memory_peak_bytes_by_device={peak_bytes}")
 
     reg.close()
     del svc, reg
@@ -331,7 +375,8 @@ def run_cell(bench: dict, cell_name: str, *, seed: int, seconds: float,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": peak_bytes}
+              "count": len(jax.devices()), "chips": run.chips,
+              "memory_peak_bytes": max(peak_bytes)}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     for c in checks.values():       # JSON has no inf: a missing number
         if not math.isfinite(c["value"]):

@@ -140,6 +140,86 @@ def test_trace_top_ops_and_labelled_gaps(profile):
                for name in red["idle_by_activity"])
 
 
+class _Plane:
+    """A device plane under another name, or one busy from ``lo`` to
+    ``hi`` on its ``XLA Modules`` line."""
+
+    def __init__(self, name, like=None, busy=None):
+        self.name, self.like, self.busy = name, like, busy
+
+    @property
+    def lines(self):            # a recorded plane's lines read once
+        if self.like is not None:
+            return self.like.lines
+        t0, t1 = self.busy
+        event = type("Event", (), {"name": "jit_f(1)", "start_ns": t0,
+                                   "duration_ns": t1 - t0})
+        return [type("Line", (), {"name": "XLA Modules",
+                                  "events": [event]})]
+
+
+def test_trace_reduced_over_the_cells_devices_only(profile):
+    """A cell's device ids select their planes, in the order of ``n`` as
+    a number; a plane of another device changes nothing.  Top ops and
+    idle gaps are the busiest device's."""
+    red = trace_reduce.reduce_profile(profile)
+    assert red["device_ids"] == [0]
+    assert red["busy_s_by_device"] == [red["busy_s"]]
+    assert trace_reduce.reduce_profile(profile, device_ids=[0]) == red
+    lo, hi = trace_reduce.find_span(trace_reduce.host_events(profile),
+                                    trace_reduce.WINDOW)
+    tpu0 = next(p for p in profile.planes if p.name == "/device:TPU:0")
+    others = [p for p in profile.planes if p is not tpu0]
+    full = _Plane("/device:TPU:2", busy=(lo, hi))
+    planes = others + [full, _Plane("/device:TPU:10", like=tpu0),
+                       _Plane("/device:TPU:0", like=tpu0)]
+    four = type("Profile", (), {"planes": planes})
+    sel = trace_reduce.reduce_profile(four, device_ids=[10, 0])
+    assert sel["device_ids"] == [0, 10]
+    assert sel["busy_s_by_device"] == [red["busy_s"]] * 2
+    assert {k: v for k, v in sel.items() if k not in (
+        "device_ids", "busy_s_by_device", "devices")} == {
+        k: v for k, v in red.items() if k not in (
+            "device_ids", "busy_s_by_device", "devices")}
+    every = trace_reduce.reduce_profile(four)
+    assert every["device_ids"] == [0, 2, 10]
+    assert every["busy_s_by_device"][1] == pytest.approx(red["window_s"])
+    assert every["busy_s"] == pytest.approx(
+        (2 * red["busy_s"] + red["window_s"]) / 3)
+    # The breakdown is the busiest device's: plane 2, busy all through the
+    # window on a line of programs alone.
+    assert every["device_ops"] == [] and every["idle_gaps"] == []
+    with pytest.raises(ValueError, match="TPU planes"):
+        trace_reduce.reduce_profile(four, device_ids=[0, 1])
+
+
+def _traced_run(chips, busy_by_device, window_s=10.0):
+    run = _run_record({}, {"kind": "closed"}, 10**8, (10**6, 10**6),
+                      calls=40, vectors=160, slots=10**8)
+    run.chips = chips
+    run.peaks = {"hbm_bytes_per_s": 819e9}
+    run.trace = {"busy_s": sum(busy_by_device) / len(busy_by_device),
+                 "busy_s_by_device": busy_by_device, "window_s": window_s}
+    return run
+
+
+def test_roofline_and_idle_share_over_four_chips():
+    """Four chips: the window's bytes over four chips' peak and the mean
+    busy time, a quarter of the one-chip formula; idle from the mean."""
+    one = _traced_run(1, [6.0])
+    four = _traced_run(4, [5.0, 6.0, 7.0, 6.0])
+    nbytes, _ = one.work()
+    assert four.work() == one.work()
+    assert trace_reduce.roofline_pct(one) == pytest.approx(
+        100 * nbytes / 819e9 / 6.0)
+    assert trace_reduce.roofline_pct(four) == pytest.approx(
+        trace_reduce.roofline_pct(one) / 4)
+    assert trace_reduce.idle_pct(one) == pytest.approx(40.0)
+    assert trace_reduce.idle_pct(four) == pytest.approx(40.0)
+    four.trace["busy_s"] = 0.0
+    assert trace_reduce.roofline_pct(four) is None
+
+
 def test_label_gap_prefers_the_most_specific_cover():
     acts = [("bench.result_wait", 0, 1000), ("repro.pack", 100, 300),
             ("xla.compile", 700, 760)]
@@ -292,10 +372,35 @@ def test_benchmark_json_keeps_the_contract(bench):
                     if cell in m.get("workloads", [cell])]
         assert len(reported) >= 2
         assert bench_run.cell_metrics(bench, cell, True)
-    assert all(c["chips"] == 1 for c in bench["workloads"])
+    chips = [c["chips"] for c in bench["workloads"]]
+    assert set(chips) <= {1, 4}
+    assert chips.count(4) <= max(1, len(chips) // 2)
 
 
 # -- the correctness check: sound, control, faults ---------------------------
+
+def test_reference_in_row_blocks_agrees_to_the_bit():
+    """The threaded row blocks give the answers of one product over the
+    whole matrix, bit for bit."""
+    import scipy.sparse as sp
+    import reference
+    spec = dict(bench_run.load_json("configs", "pokec")["matrix"],
+                **TINY["pokec"])
+    rows, cols, vals, shape = bench_run.load_reader(
+        "generators", "power_law").generate(spec, BIG_SEED)
+    a = sp.csr_matrix((vals.astype(np.float64), (rows, cols)), shape=shape)
+    xs = np.random.default_rng(3).standard_normal((5, shape[1]),
+                                                  dtype=np.float32)
+    x = xs.astype(np.float64).T
+    with reference.Reference(rows, cols, vals, shape) as one, \
+            reference.Reference(rows, cols, vals, shape, threads=7) as ref:
+        ys, mags = ref.products(xs)
+        assert np.array_equal(ys, (a @ x).T)
+        assert np.array_equal(mags, (abs(a) @ np.abs(x)).T)
+        assert np.array_equal(ref.pagerank(0.85), one.pagerank(0.85))
+        pairs = [(ys[i].astype(np.float32), ys[i], mags[i])
+                 for i in range(5)]
+        assert ref.rel_gaps(pairs) == [reference.rel_gap(*p) for p in pairs]
 
 def _tiny_run(bench, cell_name, seconds=1.0, rate=20.0, **config_changes):
     cell = next(c for c in bench["workloads"] if c["name"] == cell_name)

@@ -94,9 +94,11 @@ def test_traced_run_reports_the_span_metrics(cell, monkeypatch):
     program records are the names the readers look up."""
     import trace_reduce
 
-    def no_device(profile, extra_host=()):
+    def no_device(profile, extra_host=(), device_ids=None):
         assert any(name.startswith("repro.") for name, _, _ in extra_host)
-        return {"busy_s": 1.0, "window_s": 2.0, "devices": 1,
+        assert len(device_ids) == 1
+        return {"busy_s": 1.0, "busy_s_by_device": [1.0],
+                "device_ids": device_ids, "window_s": 2.0, "devices": 1,
                 "device_ops": [], "idle_gaps": [], "idle_by_activity": {}}
 
     monkeypatch.setattr(trace_reduce, "reduce_profile", no_device)

@@ -3,11 +3,13 @@
 The traced stretch is the host annotation ``bench.window``.  Inside it:
 
 * busy time: the union of the intervals in which a program or an op ran
-  on a device (the ``XLA Modules`` and ``XLA Ops`` lines of each
-  ``/device:TPU:n`` plane), averaged over the devices;
-* the device ops that took most self time (nested ops subtracted),
-  named ``<program>/<op>`` with the hashes and HLO operands stripped;
-* the idle gaps of the first device, each named by what the host was
+  on a device (the ``XLA Modules`` and ``XLA Ops`` lines of its
+  ``/device:TPU:n`` plane), for each of the cell's devices, and its mean
+  over them; a plane of a device outside the cell is left out;
+* the device ops that took most self time (nested ops subtracted) on the
+  cell's busiest device, the one that sets the pace, named
+  ``<program>/<op>`` with the hashes and HLO operands stripped;
+* the idle gaps of that device, each named by what the host was
   doing meanwhile: a harness annotation (``bench.*``), a program span
   mirrored onto the trace's clock (``repro.*``, see
   :func:`clock_offset_ns`), or ``xla.compile`` while XLA compiled or
@@ -20,7 +22,7 @@ import bisect
 import re
 from collections import defaultdict
 
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 BUSY_LINES = ("XLA Modules", "XLA Ops")
 WINDOW = "bench.window"
 SYNC = "bench.sync"
@@ -151,23 +153,38 @@ def label_gap(gap: tuple[int, int], activities) -> str:
     return min(covering)[1] if covering else best
 
 
-def reduce_profile(profile, extra_host=()) -> dict:
+def device_planes(profile, device_ids=None) -> dict:
+    """The ``/device:TPU:n`` planes by ``n``, in ascending order; only
+    those of ``device_ids`` where it is given.  Raises ``ValueError`` for
+    an id with no plane, or a trace with none: no device number can come
+    from it."""
+    planes = {int(m.group(1)): p for p in profile.planes
+              if (m := DEVICE_PLANE.match(p.name))}
+    ids = sorted(planes if device_ids is None else device_ids)
+    if not ids or any(i not in planes for i in ids):
+        raise ValueError(f"the trace has TPU planes {sorted(planes)}; "
+                         f"the cell's devices are {ids}")
+    return {i: planes[i] for i in ids}
+
+
+def reduce_profile(profile, extra_host=(), device_ids=None) -> dict:
     """Busy and window seconds, top device ops and labelled idle gaps of
     the ``bench.window`` stretch.  ``extra_host`` adds host activities
     ``(name, start_ns, end_ns)`` already on the trace's clock.
 
-    Raises ``ValueError`` for a trace with no TPU device plane: no
-    device number can come from it."""
+    ``device_ids`` are the cell's devices (``jax.Device.id``, the ``n``
+    of ``/device:TPU:n``); without them every TPU plane counts.  Busy time
+    is given for each (``busy_s_by_device``, by ascending id) and as their
+    mean (``busy_s``); the top ops and the idle gaps are those of the
+    busiest, the first of equals."""
     events = host_events(profile)
     lo, hi = find_span(events, WINDOW)
-    planes = sorted((p for p in profile.planes if DEVICE_PLANE.match(p.name)),
-                    key=lambda p: p.name)
-    if not planes:
-        raise ValueError("the trace has no TPU device plane")
-    lines = [_device_lines(p) for p in planes]
+    planes = device_planes(profile, device_ids)
+    lines = [_device_lines(p) for p in planes.values()]
     busy = [device_busy(ln, lo, hi) for ln in lines]
-    busy_s = sum((b - a) for bs in busy for a, b in bs) / 1e9 / len(planes)
-    edges = [lo] + [t for a, b in busy[0] for t in (a, b)] + [hi]
+    busy_by_device = [sum(b - a for a, b in bs) / 1e9 for bs in busy]
+    pace = busy_by_device.index(max(busy_by_device))
+    edges = [lo] + [t for a, b in busy[pace] for t in (a, b)] + [hi]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] > edges[i]]
     acts = _activities(events, extra_host)
@@ -176,10 +193,12 @@ def reduce_profile(profile, extra_host=()) -> dict:
     for name, s in labelled:
         by_label[name] += s
     return {
-        "busy_s": busy_s,
+        "busy_s": sum(busy_by_device) / len(planes),
+        "busy_s_by_device": busy_by_device,
+        "device_ids": list(planes),
         "window_s": (hi - lo) / 1e9,
         "devices": len(planes),
-        "device_ops": top_ops(lines[0], lo, hi),
+        "device_ops": top_ops(lines[pace], lo, hi),
         "idle_gaps": [[n, s] for n, s in
                       sorted(labelled, key=lambda g: -g[1])[:TOP]],
         "idle_by_activity": dict(sorted(by_label.items(),
@@ -189,19 +208,22 @@ def reduce_profile(profile, extra_host=()) -> dict:
 
 def roofline_pct(run) -> float | None:
     """Share of the HBM roofline: the window's counted bytes
-    (``bench/work.py``) at the chip's peak bandwidth (``bench/peaks.json``)
-    over the device's busy time in the traced window.  HBM bounds SpMV:
-    2 flops per 8 or more bytes lies far below the chip's ridge."""
+    (``bench/work.py``) at the peak bandwidth of the cell's ``run.chips``
+    chips (``bench/peaks.json``) over the devices' mean busy time in the
+    traced window.  HBM bounds SpMV: 2 flops per 8 or more bytes lies far
+    below the chip's ridge."""
     if run.trace is None or run.trace["busy_s"] <= 0:
         return None
     nbytes, _ = run.work()
     if nbytes == 0:
         return None
-    return 100.0 * nbytes / run.peaks["hbm_bytes_per_s"] / run.trace["busy_s"]
+    peak = run.peaks["hbm_bytes_per_s"] * run.chips
+    return 100.0 * nbytes / peak / run.trace["busy_s"]
 
 
 def idle_pct(run) -> float | None:
-    """Share of the traced window in which no program ran on the device."""
+    """Share of the traced window in which no program ran on the cell's
+    devices, on average over them."""
     if run.trace is None or run.trace["window_s"] <= 0:
         return None
     return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

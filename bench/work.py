@@ -11,6 +11,12 @@ vectors reads the matrix once and each vector and result once:
 
 with 4 bytes of index per non-zero.  A format that stores less index
 than that could read above its roofline; see ``PERF.md``.
+
+A plan over several chips counts the same: a row plan reads x once on
+each chip (x is replicated to every chip of the mesh), and a column plan
+writes a partial y on each; those copies are waste, not work.  The
+roofline divides these counts by the peak of all the cell's chips
+together (``trace_reduce.roofline_pct``).
 """
 from __future__ import annotations
 
